@@ -347,6 +347,8 @@ def main(smoke: bool = False, scenario: str = "gpt", stages: int = 0,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small CI variant (writes BENCH_e2e*_smoke.json)")

@@ -17,10 +17,10 @@ import json
 import time
 from pathlib import Path
 
-import jax
 
 from repro.configs.base import SHAPES, get_arch
 from repro.core.cost_model import V5E
+from repro.dist.sharding import set_mesh
 from repro.launch import hlo_cost
 from repro.launch.dryrun import _lower_cell
 from repro.launch.mesh import make_production_mesh
@@ -65,7 +65,7 @@ def measure(arch: str, shape_name: str, variant: str, multi_pod=False) -> dict:
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = 512 if multi_pod else 256
     t0 = time.time()
-    with jax.set_mesh(mesh):
+    with set_mesh(mesh):
         compiled = _lower_cell(cfg, shape, mesh, AdamWConfig()).compile()
         hc = hlo_cost.analyze(compiled.as_text())
         ma = compiled.memory_analysis()

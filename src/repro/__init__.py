@@ -12,17 +12,11 @@ Package layout (see docs/architecture.md for the data-flow walkthrough):
 - ``repro.launch``  — mesh factories and the multi-pod compile dry-run.
 - ``repro.kernels`` — Pallas kernels + jnp reference implementations.
 
-Importing ``repro`` installs the JAX forward-compat shims (see
-``repro._jax_compat``) so the unified post-0.6 sharding API used throughout
-the codebase also runs on older jax runtimes.
-
 The public surface re-exports lazily (PEP 562) so ``import repro`` stays
 cheap and the submodule import graph keeps its layering::
 
     from repro import PlanAheadRunner, RunnerConfig, make_backend
 """
-from repro import _jax_compat  # noqa: F401  (imported for its side effects)
-
 # public name -> defining module; resolved on first attribute access
 _PUBLIC = {
     # execution backends (the ExecutionBackend protocol, ISSUE 8)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import multiprocessing
+import os
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -194,6 +195,16 @@ def _plan_job(lengths, cost, pcfg: PlannerConfig) -> IterationPlan:
     return plan_iteration(lengths, cost, pcfg)
 
 
+def _cpu_only_worker() -> None:
+    """Initializer of planner worker processes. Planning needs no device,
+    and a TPU belongs to the one process that loaded its library — the
+    parent. Pin the worker to the CPU before anything in it asks JAX for a
+    device (jax may already be imported, with the parent's main module)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 class PlannerPool:
     """Overlaps plan generation with execution (paper §3): a worker pool
     plans future iterations ahead of the executor and pushes them to the
@@ -214,7 +225,8 @@ class PlannerPool:
       Workers are spawned, not forked — importing ``repro`` loads jax, and
       forking a multithreaded jax parent risks deadlock — so worker startup
       pays one interpreter+import per process; the pool is long-lived, so
-      that cost amortizes across the training run.
+      that cost amortizes across the training run. Workers run with
+      ``JAX_PLATFORMS=cpu``, so none can take the parent's TPU.
     """
 
     def __init__(self, store: InstructionStore, n_workers: int = 4,
@@ -225,7 +237,8 @@ class PlannerPool:
         if use_processes:
             self.pool = cf.ProcessPoolExecutor(
                 max_workers=n_workers,
-                mp_context=multiprocessing.get_context("spawn"))
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_cpu_only_worker)
         else:
             self.pool = cf.ThreadPoolExecutor(max_workers=n_workers)
         self.futures: dict[int, cf.Future] = {}

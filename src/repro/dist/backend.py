@@ -109,9 +109,22 @@ class ExecutionBackend:
         """Place optimizer state for this backend (default: leave as-is)."""
         return opt_state
 
-    def optimizer_step(self, params, grads, opt_state, opt_cfg):
-        """Apply one optimizer update (default: eager AdamW)."""
+    def optimizer_step(self, params, grads, opt_state, opt_cfg,
+                       grad_scale: float = 1.0):
+        """Apply one optimizer update to ``grads * grad_scale`` (default:
+        eager AdamW)."""
+        grads = jax.tree.map(lambda g: g * grad_scale, grads)
         return adamw_update(params, grads, opt_state, opt_cfg)
+
+
+def _scaled_adamw(opt_cfg, *, donate: bool):
+    """jit of ``adamw_update`` over ``grads * scale``. A Python-float scale
+    is weakly typed, so bf16 grads scale in bf16 exactly as an eager
+    ``g * scale`` would."""
+    def step(params, grads, opt_state, scale):
+        grads = jax.tree.map(lambda g: g * scale, grads)
+        return adamw_update(params, grads, opt_state, opt_cfg)
+    return jax.jit(step, donate_argnums=(0, 1, 2) if donate else ())
 
 
 def _timed_callbacks(cbs: list[StageCallbacks], records: list, lock):
@@ -165,6 +178,7 @@ class ThreadsBackend(ExecutionBackend):
             else CompiledStepCache()
         self.exec_timeout = exec_timeout
         self.strict = strict
+        self._opt_steps: dict = {}
         if cfg.family == "encdec":
             # total periods = enc + dec; the layout also requires the stage
             # boundary to coincide with the enc/dec split
@@ -180,6 +194,17 @@ class ThreadsBackend(ExecutionBackend):
             self.pm = (PipelinedModel(cfg, None, n_stages, impl=impl,
                                       step_cache=self.step_cache)
                        if pipelined else None)
+
+    def optimizer_step(self, params, grads, opt_state, opt_cfg,
+                       grad_scale: float = 1.0):
+        """AdamW compiled once, with params, grads and optimizer state
+        donated: the update happens in place, where an eager one would hold
+        a second copy of the fp32 state (gigabytes at published widths).
+        The arrays passed in are consumed."""
+        key = repr(opt_cfg)
+        if key not in self._opt_steps:
+            self._opt_steps[key] = _scaled_adamw(opt_cfg, donate=True)
+        return self._opt_steps[key](params, grads, opt_state, grad_scale)
 
     def _grad_fn(self, shape: tuple):
         """shape: (mbs, seq) decoder-only or (mbs, enc, dec) enc-dec."""
@@ -220,6 +245,7 @@ class ThreadsBackend(ExecutionBackend):
             if collect_timings:
                 cbs = _timed_callbacks(cbs, records, threading.Lock())
             PipelineExecutor(plan, cbs, timeout=timeout, hook=hook).run()
+            del cbs     # drops the stage param slices before the merge
             grads = pm.merge_stage_grads(result["stage_grads"])
             return BackendResult(grads, result["loss_sum"],
                                  result["weight_sum"], records)
@@ -478,15 +504,15 @@ class MeshBackend(ExecutionBackend):
                 x, NamedSharding(mesh, spec_for_zero(x.shape, zl, mesh)))
         return jax.tree.map(place, opt_state)
 
-    def optimizer_step(self, params, grads, opt_state, opt_cfg):
+    def optimizer_step(self, params, grads, opt_state, opt_cfg,
+                       grad_scale: float = 1.0):
         """AdamW under jit so XLA partitions the update over the ZeRO
         shards: each device updates only its 1/S slice of (master, m, v)
         and the new params materialize from the sharded master."""
         key = ("mesh_opt", *self._ns, repr(opt_cfg))
         fn = self.step_cache.get(
-            key, lambda: jax.jit(
-                lambda p, g, o: adamw_update(p, g, o, opt_cfg)))
-        return fn(params, grads, opt_state)
+            key, lambda: _scaled_adamw(opt_cfg, donate=False))
+        return fn(params, grads, opt_state, grad_scale)
 
 
 def make_backend(name: str, cfg: ArchConfig, n_stages: int, *,

@@ -926,8 +926,17 @@ def run_process_cluster(cfg, cost, pcfg, rcfg, stream, opt_cfg=None,
     evidence: delivered kills with verified-dead pids, election/membership
     events, and the orphan count after teardown.
     """
+    import jax
+
     from repro.train.runner import RunnerStats
 
+    if jax.default_backend() == "tpu":
+        # each replica is a process doing its own JAX compute, and a TPU
+        # belongs to one process: the second replica could not load it
+        raise RuntimeError(
+            "fault_domain='process' starts one JAX process per replica, and "
+            "a TPU belongs to one process; this fault domain is a CPU test "
+            "harness. Train on the chip with fault_domain='thread'.")
     if opt_cfg is None:
         from repro.train.optimizer import AdamWConfig
         opt_cfg = AdamWConfig(lr=3e-4)

@@ -161,7 +161,6 @@ def _pipelined_shardmap(stage_fn, stage_params, xs, mesh, axis, n_stages):
         return jax.lax.psum(outs, axis)
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    # jax.shard_map: native on new runtimes, _jax_compat shim on 0.4.x
     run = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
                         check_vma=False)
     return run(stage_params, xs)

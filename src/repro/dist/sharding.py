@@ -49,7 +49,6 @@ from typing import Optional, Sequence, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import _jax_compat
 
 LogicalDim = Union[str, None, tuple]
 
@@ -65,22 +64,26 @@ _tls = threading.local()
 # ----------------------------------------------------------------------
 # ambient mesh + pure-DP mode
 # ----------------------------------------------------------------------
-def ambient_mesh() -> Optional[Mesh]:
-    """The mesh of the enclosing ``jax.set_mesh`` block, or None.
+@contextlib.contextmanager
+def set_mesh(mesh: Mesh):
+    """``jax.set_mesh`` that also records ``mesh`` for :func:`ambient_mesh`.
 
-    Falls back to the legacy ``with mesh:`` resource context so code that
-    predates ``set_mesh`` still resolves.
+    JAX hands out the concrete context mesh only outside ``jax.jit``
+    (``jax.sharding.get_mesh``), and the model code asks for it while it
+    is traced, so the mesh is kept in a thread-local as well.
     """
-    mesh = _jax_compat.current_set_mesh()
-    if mesh is not None:
-        return mesh
-    # legacy thread resource env (jax 0.4.x `with mesh:`)
-    with contextlib.suppress(Exception):
-        from jax._src import mesh as mesh_lib
-        phys = mesh_lib.thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys
-    return None
+    prev = getattr(_tls, "mesh", None)
+    _tls.mesh = mesh
+    try:
+        with jax.set_mesh(mesh):
+            yield mesh
+    finally:
+        _tls.mesh = prev
+
+
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing :func:`set_mesh` block, or None."""
+    return getattr(_tls, "mesh", None)
 
 
 def is_pure_dp() -> bool:

@@ -23,15 +23,19 @@ FLOPs of forward):
 
 GQA is native: k/v carry ``kv_heads`` and the index maps address
 ``q_head // group`` directly — no head-repeated K/V is ever materialized
-in HBM. Positions (and segment ids, for the ragged wrapper) stay ``(B, T)``
-arrays read through BlockSpec index maps — never repeated to ``B*H`` rows.
+in HBM. Positions (and segment ids, for the ragged wrapper) are ``(B, T)``
+arrays, reshaped (not repeated) to a column or row per batch row and read
+through BlockSpec index maps — never repeated to ``B*H`` rows.
 
-BlockSpec tiling (defaults): Q tile (block_q=512, d_head), K/V tiles
-(block_kv=512, d_head) — multiples of the 128-lane MXU dimension. Forward
-VMEM working set ≈ (block_q + 2·block_kv)·d·2B + block_q·(d+2)·4B
-≈ 1.6 MiB at d=128; the dk/dv pass peaks at (2·block_q + 2·block_kv)·d·2B
-+ 2·block_kv·d·4B + block_q·block_kv·4B ≈ 2.6 MiB — both comfortably
-inside the ~16 MiB VMEM budget.
+BlockSpec tiling: every block's last two dims are a multiple of (8, 128)
+or the full array dims, which the TPU lowering requires (the layout table
+is above the pallas_call builders). Q tile (block_q, Dp), K/V tiles
+(block_kv, Dp) with block_q = block_kv = 512 by default and D padded to
+Dp = 128·⌈D/128⌉; :func:`shrink_block` picks legal sequence tiles for any
+palette bucket. Forward VMEM working set ≈ (block_q + 2·block_kv)·d·2B +
+block_q·(d+2)·4B ≈ 1.6 MiB at d=128; the dk/dv pass peaks at
+(2·block_q + 2·block_kv)·d·2B + 2·block_kv·d·4B + block_q·block_kv·4B
+≈ 2.6 MiB — both inside the ~16 MiB VMEM budget.
 """
 from __future__ import annotations
 
@@ -45,20 +49,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128
 
 
 def shrink_block(length: int, block: int) -> int:
-    """Largest divisor of ``length`` that also divides ``block``.
+    """The tile length the kernels use along a sequence of ``length``.
 
-    Blocks must tile the sequence exactly. When a bucketed length is not a
-    multiple of the requested block (e.g. palette bucket 768 with block
-    512), shrink to the gcd so alignment factors (128/64/32 buckets)
-    survive instead of asserting.
+    Blocks must tile the sequence exactly, and a block's length is also the
+    lane dimension of the kv-side id rows, which the TPU lowering accepts
+    only as a multiple of 128 or as the whole sequence. So: a sequence no
+    longer than ``block`` is one block; a longer one takes the largest
+    multiple of 128 that divides it and is at most ``block`` (palette
+    bucket 768 under block 512 -> 384). A longer sequence with no such
+    divisor is one block too; a palette built with ``seq_align=128`` never
+    produces one.
     """
-    block = min(block, length)
-    if length % block:
-        block = math.gcd(length, block)
-    return block
+    if length <= block:
+        return length
+    for cand in range(block - block % _LANES, 0, -_LANES):
+        if length % cand == 0:
+            return cand
+    return length
 
 
 # ----------------------------------------------------------------------
@@ -123,6 +134,12 @@ def live_block_mask(q_positions, kv_positions,
 
 # ----------------------------------------------------------------------
 # kernel bodies (segment refs are None for the plain flash path)
+#
+# Per-token int data enters the kernels in two orientations: query-side
+# positions/segment ids as (block_q, 1) columns, kv-side ones as
+# (1, block_kv) rows, so ``q - k`` and ``q == k`` broadcast straight to the
+# (block_q, block_kv) score tile. Row statistics (m, l, lse, delta) are
+# (block_q, 1) columns for the same reason.
 # ----------------------------------------------------------------------
 def _block_stats(qpos, kpos, qseg, kseg, causal, window):
     qp = (jnp.min(qpos), jnp.max(qpos))
@@ -136,11 +153,12 @@ def _block_stats(qpos, kpos, qseg, kseg, causal, window):
 
 
 def _element_mask(qpos, kpos, qseg, kseg, causal, window):
+    """(block_q, block_kv) mask from (block_q, 1) / (1, block_kv) ids."""
     mask = None
     if qseg is not None:
-        mask = (qseg[:, None] == kseg[None, :]) & (kseg[None, :] >= 0)
+        mask = (qseg == kseg) & (kseg >= 0)
     if causal:
-        dpos = qpos[:, None] - kpos[None, :]
+        dpos = qpos - kpos
         cm = dpos >= 0
         if window > 0:
             cm &= dpos < window
@@ -159,6 +177,12 @@ def _scores(q, k, sm_scale, softcap):
     return s0, None
 
 
+def _read_ids(qpos_ref, kpos_ref, qseg_ref, kseg_ref):
+    qseg = qseg_ref[...] if qseg_ref is not None else None
+    kseg = kseg_ref[...] if kseg_ref is not None else None
+    return qpos_ref[...], kpos_ref[...], qseg, kseg
+
+
 def _fwd_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
               q_ref, k_ref, v_ref, o_ref, lse_ref,
               m_ref, l_ref, acc_ref, *,
@@ -171,29 +195,27 @@ def _fwd_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qpos, kpos = qpos_ref[0], kpos_ref[0]
-    qseg = qseg_ref[0] if qseg_ref is not None else None
-    kseg = kseg_ref[0] if kseg_ref is not None else None
+    qpos, kpos, qseg, kseg = _read_ids(qpos_ref, kpos_ref, qseg_ref, kseg_ref)
     live = _block_stats(qpos, kpos, qseg, kseg, causal, window)
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)     # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)          # (bq, d)
+        k = k_ref[...].astype(jnp.float32)          # (bk, d)
+        v = v_ref[...].astype(jnp.float32)
         s, _ = _scores(q, k, sm_scale, softcap)
         mask = _element_mask(qpos, kpos, qseg, kseg, causal, window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                         # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
+        p = jnp.exp(s - m_cur)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_cur
@@ -201,8 +223,8 @@ def _fwd_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
     @pl.when(kv_idx == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_ref[...] + jnp.log(l)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
 def _p_and_ds(q, k, qpos, kpos, qseg, kseg, lse, do, v, delta,
@@ -210,13 +232,13 @@ def _p_and_ds(q, k, qpos, kpos, qseg, kseg, lse, do, v, delta,
     """Recompute p from residuals and chain d(loss)/d(raw logits)."""
     s1, th = _scores(q, k, sm_scale, softcap)
     mask = _element_mask(qpos, kpos, qseg, kseg, causal, window)
-    p = jnp.exp(s1 - lse[:, None])
+    p = jnp.exp(s1 - lse)
     if mask is not None:
         # also zeroes fully-masked rows, whose lse is the -inf sentinel
         p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if softcap is not None:
         ds = ds * (1.0 - th * th)      # through s1 = cap * tanh(s0 / cap)
     return p, ds
@@ -232,27 +254,24 @@ def _dq_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    qpos, kpos = qpos_ref[0], kpos_ref[0]
-    qseg = qseg_ref[0] if qseg_ref is not None else None
-    kseg = kseg_ref[0] if kseg_ref is not None else None
+    qpos, kpos, qseg, kseg = _read_ids(qpos_ref, kpos_ref, qseg_ref, kseg_ref)
     live = _block_stats(qpos, kpos, qseg, kseg, causal, window)
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        _, ds = _p_and_ds(q, k, qpos, kpos, qseg, kseg,
-                          lse_ref[0, 0, :], do, v, delta_ref[0, 0, :],
-                          causal, window, softcap, sm_scale)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        _, ds = _p_and_ds(q, k, qpos, kpos, qseg, kseg, lse_ref[...], do, v,
+                          delta_ref[...], causal, window, softcap, sm_scale)
         dq_acc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
 
     @pl.when(kv_idx == n_kv_blocks - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
@@ -267,20 +286,17 @@ def _dkv_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    qpos, kpos = qpos_ref[0], kpos_ref[0]
-    qseg = qseg_ref[0] if qseg_ref is not None else None
-    kseg = kseg_ref[0] if kseg_ref is not None else None
+    qpos, kpos, qseg, kseg = _read_ids(qpos_ref, kpos_ref, qseg_ref, kseg_ref)
     live = _block_stats(qpos, kpos, qseg, kseg, causal, window)
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        p, ds = _p_and_ds(q, k, qpos, kpos, qseg, kseg,
-                          lse_ref[0, 0, :], do, v, delta_ref[0, 0, :],
-                          causal, window, softcap, sm_scale)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        p, ds = _p_and_ds(q, k, qpos, kpos, qseg, kseg, lse_ref[...], do, v,
+                          delta_ref[...], causal, window, softcap, sm_scale)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -290,8 +306,8 @@ def _dkv_body(qpos_ref, kpos_ref, qseg_ref, kseg_ref,
 
     @pl.when((g == group - 1) & (q_idx == n_q_blocks - 1))
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _plain(body):
@@ -303,16 +319,58 @@ def _plain(body):
 
 # ----------------------------------------------------------------------
 # pallas_call builders
+#
+# Layout seen by the kernels (every block's last two dims are either a
+# multiple of (8, 128) or the full array dims, as the TPU lowering needs):
+#   q/k/v/o/do/dq/dk/dv  (B, T, H*Dp)  blocks (block, Dp) at column
+#                        block ``head`` — a free reshape of (B, T, H, D),
+#                        with D zero-padded up to Dp = 128·⌈D/128⌉
+#   query-side ids       (B, T, 1)     blocks (block_q, 1)
+#   kv-side ids          (B, 1, S)     blocks (1, block_kv)
+#   lse / delta          (B, H, T, 1)  blocks (block_q, 1)
 # ----------------------------------------------------------------------
-def _seq_specs(block_q, block_kv, index_q, index_kv, segmented):
-    """(B, T)-shaped int inputs: positions (+ segment ids when ragged),
-    addressed directly by block index maps — never repeated per head."""
-    specs = [pl.BlockSpec((1, block_q), index_q),
-             pl.BlockSpec((1, block_kv), index_kv)]
-    if segmented:
-        specs += [pl.BlockSpec((1, block_q), index_q),
-                  pl.BlockSpec((1, block_kv), index_kv)]
-    return specs
+def _heads_flat(x, dp):
+    """(B, T, H, D) -> (B, T, H*Dp), zero-padding D to the lane width."""
+    b, t, h, d = x.shape
+    if d != dp:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, dp - d)))
+    return x.reshape(b, t, h * dp)
+
+
+def _heads_unflat(x, h, d):
+    b, t, _ = x.shape
+    return x.reshape(b, t, h, -1)[..., :d]
+
+
+def _id_args(q_positions, kv_positions, q_segment_ids, kv_segment_ids):
+    """Query-side ids as (B, T, 1) columns, kv-side ids as (B, 1, S) rows
+    (positions first, then segment ids when ragged)."""
+    args = [q_positions[:, :, None], kv_positions[:, None, :]]
+    if q_segment_ids is not None:
+        args += [q_segment_ids[:, :, None], kv_segment_ids[:, None, :]]
+    return args
+
+
+def _id_specs(block_q, block_kv, qi, ki, segmented):
+    """BlockSpecs for :func:`_id_args`; ``qi``/``ki`` map a grid point to
+    (batch, q block) / (batch, kv block)."""
+    def q_map(*g):
+        b_, iq = qi(*g)
+        return (b_, iq, 0)
+
+    def k_map(*g):
+        b_, ik = ki(*g)
+        return (b_, 0, ik)
+    specs = [pl.BlockSpec((None, block_q, 1), q_map),
+             pl.BlockSpec((None, 1, block_kv), k_map)]
+    return specs * 2 if segmented else specs
+
+
+def _dims(q, k):
+    b, t, h, d = q.shape
+    s, kvh = k.shape[1], k.shape[2]
+    assert h % kvh == 0, (h, kvh)
+    return b, t, h, d, s, kvh, h // kvh, -(-d // _LANES) * _LANES
 
 
 def mha_forward(q, k, v, q_positions, kv_positions,
@@ -320,10 +378,7 @@ def mha_forward(q, k, v, q_positions, kv_positions,
                 causal, window=0, softcap=None,
                 block_q, block_kv, interpret=False):
     """Raw forward: returns ``(o, lse)`` with lse in (B, H, T) fp32."""
-    b, t, h, d = q.shape
-    s, kvh = k.shape[1], k.shape[2]
-    assert h % kvh == 0, (h, kvh)
-    group = h // kvh
+    b, t, h, d, s, kvh, group, dp = _dims(q, k)
     block_q = shrink_block(t, block_q)
     block_kv = shrink_block(s, block_kv)
     nq, nk = t // block_q, s // block_kv
@@ -334,44 +389,43 @@ def mha_forward(q, k, v, q_positions, kv_positions,
         body, causal=causal, window=window, softcap=softcap,
         sm_scale=1.0 / math.sqrt(d), n_kv_blocks=nk)
 
-    in_specs = _seq_specs(
+    in_specs = _id_specs(
         block_q, block_kv,
         lambda b_, h_, iq, ik: (b_, iq),
         lambda b_, h_, iq, ik: (b_, ik),
         segmented,
     ) + [
-        pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        pl.BlockSpec((1, block_kv, 1, d),
-                     lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-        pl.BlockSpec((1, block_kv, 1, d),
-                     lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
+        pl.BlockSpec((None, block_q, dp), lambda b_, h_, iq, ik: (b_, iq, h_)),
+        pl.BlockSpec((None, block_kv, dp),
+                     lambda b_, h_, iq, ik: (b_, ik, h_ // group)),
+        pl.BlockSpec((None, block_kv, dp),
+                     lambda b_, h_, iq, ik: (b_, ik, h_ // group)),
     ]
-    args = [q_positions, kv_positions]
-    if segmented:
-        args += [q_segment_ids, kv_segment_ids]
-    args += [q, k, v]
+    args = _id_args(q_positions, kv_positions, q_segment_ids, kv_segment_ids)
+    args += [_heads_flat(x, dp) for x in (q, k, v)]
 
     o, lse = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik: (b_, h_, iq)),
+            pl.BlockSpec((None, block_q, dp),
+                         lambda b_, h_, iq, ik: (b_, iq, h_)),
+            pl.BlockSpec((None, None, block_q, 1),
+                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
+            jax.ShapeDtypeStruct((b, t, h * dp), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, dp), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return o, lse
+    return _heads_unflat(o, h, d), lse[..., 0]
 
 
 def mha_backward(q, k, v, q_positions, kv_positions,
@@ -379,9 +433,7 @@ def mha_backward(q, k, v, q_positions, kv_positions,
                  causal, window=0, softcap=None,
                  block_q, block_kv, interpret=False):
     """Fused backward from residuals: returns ``(dq, dk, dv)``."""
-    b, t, h, d = q.shape
-    s, kvh = k.shape[1], k.shape[2]
-    group = h // kvh
+    b, t, h, d, s, kvh, group, dp = _dims(q, k)
     block_q = shrink_block(t, block_q)
     block_kv = shrink_block(s, block_kv)
     nq, nk = t // block_q, s // block_kv
@@ -390,90 +442,71 @@ def mha_backward(q, k, v, q_positions, kv_positions,
 
     # delta_i = sum_d do_i * o_i — one fused elementwise-reduce over (B,T,H,D)
     delta = jnp.einsum("bthd,bthd->bht", do.astype(jnp.float32),
-                       o.astype(jnp.float32))
+                       o.astype(jnp.float32))[..., None]
 
-    args = [q_positions, kv_positions]
-    if segmented:
-        args += [q_segment_ids, kv_segment_ids]
+    lse = lse[..., None]
+    ids = _id_args(q_positions, kv_positions, q_segment_ids, kv_segment_ids)
+    qf, kf, vf, dof = (_heads_flat(x, dp) for x in (q, k, v, do))
 
     # ---- dq: q-major, kv innermost ----
     body = _dq_body if segmented else _plain(_dq_body)
     dq_kernel = functools.partial(
         body, causal=causal, window=window, softcap=softcap,
         sm_scale=sm_scale, n_kv_blocks=nk)
+    q_tile = pl.BlockSpec((None, block_q, dp),
+                          lambda b_, h_, iq, ik: (b_, iq, h_))
+    kv_tile = pl.BlockSpec((None, block_kv, dp),
+                           lambda b_, h_, iq, ik: (b_, ik, h_ // group))
+    row_stat = pl.BlockSpec((None, None, block_q, 1),
+                            lambda b_, h_, iq, ik: (b_, h_, iq, 0))
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b, h, nq, nk),
-        in_specs=_seq_specs(
+        in_specs=_id_specs(
             block_q, block_kv,
             lambda b_, h_, iq, ik: (b_, iq),
             lambda b_, h_, iq, ik: (b_, ik),
             segmented,
-        ) + [
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h_, iq, ik: (b_, h_, iq)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h_, iq, ik: (b_, h_, iq)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ) + [q_tile, kv_tile, kv_tile, q_tile, row_stat, row_stat],
+        out_specs=q_tile,
+        out_shape=jax.ShapeDtypeStruct((b, t, h * dp), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         interpret=interpret,
-    )(*args, q, k, v, do, lse, delta)
+    )(*ids, qf, kf, vf, dof, lse, delta)
 
     # ---- dk/dv: kv-major, (q-head group x q blocks) innermost ----
     body = _dkv_body if segmented else _plain(_dkv_body)
     dkv_kernel = functools.partial(
         body, causal=causal, window=window, softcap=softcap,
         sm_scale=sm_scale, n_q_blocks=nq, group=group)
+    q_tile = pl.BlockSpec((None, block_q, dp),
+                          lambda b_, kh, ik, g, iq: (b_, iq, kh * group + g))
+    kv_tile = pl.BlockSpec((None, block_kv, dp),
+                           lambda b_, kh, ik, g, iq: (b_, ik, kh))
+    row_stat = pl.BlockSpec((None, None, block_q, 1),
+                            lambda b_, kh, ik, g, iq: (b_, kh * group + g, iq, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b, kvh, nk, group, nq),
-        in_specs=_seq_specs(
+        in_specs=_id_specs(
             block_q, block_kv,
             lambda b_, kh, ik, g, iq: (b_, iq),
             lambda b_, kh, ik, g, iq: (b_, ik),
             segmented,
-        ) + [
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, iq, kh * group + g, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, ik, kh, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, ik, kh, 0)),
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, iq, kh * group + g, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, kh, ik, g, iq: (b_, kh * group + g, iq)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, kh, ik, g, iq: (b_, kh * group + g, iq)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, ik, kh, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, kh, ik, g, iq: (b_, ik, kh, 0)),
-        ],
+        ) + [q_tile, kv_tile, kv_tile, q_tile, row_stat, row_stat],
+        out_specs=[kv_tile, kv_tile],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, kvh, d), k.dtype),
-            jax.ShapeDtypeStruct((b, s, kvh, d), v.dtype),
+            jax.ShapeDtypeStruct((b, s, kvh * dp), k.dtype),
+            jax.ShapeDtypeStruct((b, s, kvh * dp), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
+            pltpu.VMEM((block_kv, dp), jnp.float32),
+            pltpu.VMEM((block_kv, dp), jnp.float32),
         ],
         interpret=interpret,
-    )(*args, q, k, v, do, lse, delta)
-    return dq, dk, dv
+    )(*ids, qf, kf, vf, dof, lse, delta)
+    return (_heads_unflat(dq, h, d), _heads_unflat(dk, kvh, d),
+            _heads_unflat(dv, kvh, d))
 
 
 def _int_ct(x):

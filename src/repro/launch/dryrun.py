@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs.base import (ARCH_IDS, SHAPES, ArchConfig, ShapeSpec,
                                 cell_supported, get_arch)
-from repro.dist.sharding import spec_for
+from repro.dist.sharding import set_mesh, spec_for
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as MD
 from repro.models import transformer as T
@@ -311,20 +311,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     n_chips = int(np.prod(list(mesh.shape.values())))
     opt_cfg = AdamWConfig()
 
-    with jax.set_mesh(mesh):
+    with set_mesh(mesh):
         lowered = _lower_cell(cfg, shape, mesh, opt_cfg)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
         ma = compiled.memory_analysis()
-        # older jaxlib has no peak stat; estimate the upper bound as
-        # args+outputs+temps minus aliased (donated) bytes, which would
-        # otherwise be double-counted on both the argument and output side
-        peak_bytes = getattr(
-            ma, "peak_memory_in_bytes",
-            ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+        peak_bytes = ma.peak_memory_in_bytes
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0]
@@ -406,7 +400,7 @@ def reanalyze_cell(arch: str, shape_name: str, multi_pod: bool) -> None:
     cfg = get_arch(arch)
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with jax.set_mesh(mesh):
+    with set_mesh(mesh):
         compiled = _lower_cell(cfg, shape, mesh, AdamWConfig()).compile()
         text = compiled.as_text()
         from repro.launch import hlo_cost

@@ -224,24 +224,76 @@ class PipelinedModel:
         return p
 
     def merge_stage_grads(self, stage_grads: list):
-        """Sum per-stage grad trees back into a full-params tree."""
-        out = jax.tree.map(jnp.zeros_like, self.full_params)
-        stack_slices = [g["stack"] for g in stage_grads]
-        full_stack = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
-                                  *stack_slices)
-        out = dict(out, stack=full_stack)
-        for j, g in enumerate(stage_grads):
+        """Sum per-stage grad trees back into a full-params tree. Built
+        from the stage grads alone: no full-size zero tree is allocated,
+        which at published widths would cost a second copy of the grads."""
+        out = {"stack": jax.tree.map(
+            lambda *xs: jnp.concatenate(xs, axis=0),
+            *[g["stack"] for g in stage_grads])}
+        for g in stage_grads:
             for key, val in g.items():
-                if key == "stack":
-                    continue
-                out[key] = out[key] + val if key in out else val
+                if key != "stack":
+                    out[key] = out[key] + val if key in out else val
         return out
 
     # ------------------------- stage compute ---------------------------
-    def _stage_fn(self, j: int, sparams, x_or_batch, batch_aux):
-        """Pure function: stage forward. Returns h_out or (loss_sum, w_sum)."""
-        return _stage_apply(self.cfg, self.k, self.n_stages, self.impl, j,
-                            sparams, x_or_batch, batch_aux)
+    def _keys(self, j: int, shape: tuple):
+        return (("fwd", self._cache_ns, j) + shape,
+                ("bwd", self._cache_ns, j) + shape)
+
+    def compile_plan(self, plan: ExecutionPlan, batches: dict) -> None:
+        """Compile every stage program the plan needs and the cache lacks.
+
+        Runs before any stage thread starts, so a compile never counts
+        against the executor's channel timeout (a full-width stage program
+        takes seconds to compile). Programs are compiled ahead of time from
+        shapes, and the cache holds the executables: the stage threads only
+        ever run compiled code. The backward programs take the stage's grad
+        accumulator as a donated argument and return it updated in place.
+        """
+        c = self.n_stages
+        firsts: dict[tuple, int] = {}
+        for m in plan.micro_batches:
+            firsts.setdefault(self._batch_shape(batches[m.mb_id]), m.mb_id)
+        # cached programs close over only static config — never ``self`` —
+        # so a shared step cache that outlives this model does not pin the
+        # retired instance (and its full_params) in memory
+        apply_fn, static = self._apply_fn, self._apply_static
+        for shape, mb in firsts.items():
+            if all(k in self.step_cache.keys()
+                   for j in range(c) for k in self._keys(j, shape)):
+                continue
+            b = {k: _struct(jnp.asarray(v)) for k, v in batches[mb].items()}
+            aux = {k: b[k] for k in self._aux_keys if k in b}
+            x = b
+            for j in range(c):
+                sp = jax.eval_shape(lambda j=j: self.stage_params(j))
+                fwd_key, bwd_key = self._keys(j, shape)
+
+                def fwd(sp_, x_, aux_, j=j):
+                    return apply_fn(*static, j, sp_, x_, aux_)
+                self.step_cache.get(fwd_key, lambda: jax.jit(fwd).lower(
+                    sp, x, aux).compile())
+                if j == c - 1:
+                    def bwd(sp_, x_, aux_, acc, j=j):
+                        def scalar(p, x2):
+                            return apply_fn(*static, j, p, x2, aux_)[0]
+                        gp, gx = jax.grad(scalar, argnums=(0, 1))(sp_, x_)
+                        return jax.tree.map(jnp.add, acc, gp), gx
+                    args = (sp, x, aux, sp)
+                else:
+                    def bwd(sp_, x_, g_out, aux_, acc, j=j):
+                        _, vjp = jax.vjp(
+                            lambda p, x2: apply_fn(*static, j, p, x2, aux_),
+                            sp_, x_)
+                        gp, gx = vjp(g_out)
+                        return jax.tree.map(jnp.add, acc, gp), gx
+                    x_next = jax.eval_shape(fwd, sp, x, aux)
+                    args = (sp, x, x_next, aux, sp)
+                self.step_cache.get(bwd_key, lambda: jax.jit(
+                    bwd, donate_argnums=len(args) - 1).lower(*args).compile())
+                if j < c - 1:
+                    x = x_next
 
     # ------------------------- callbacks -------------------------------
     def make_callbacks(self, plan: ExecutionPlan, batches: dict,
@@ -249,8 +301,11 @@ class PipelinedModel:
         """batches: mb_id -> batch dict (numpy/JAX arrays).
 
         Returns (callbacks, result) where result collects
-        {"stage_grads", "loss_sum", "weight_sum"} after run().
+        {"stage_grads", "loss_sum", "weight_sum"} after run(). Every stage
+        program is compiled here (:meth:`compile_plan`), before the
+        callbacks exist.
         """
+        self.compile_plan(plan, batches)
         c = self.n_stages
         result = {
             "stage_grads": [None] * c,
@@ -266,22 +321,9 @@ class PipelinedModel:
             b = batches[mb]
             return {k: b[k] for k in aux_keys if k in b}
 
-        def shape_of(mb):
-            return self._batch_shape(batches[mb])
-
-        # cached jits must close over only static config — never ``self`` —
-        # so a shared step cache that outlives this PipelinedModel does not
-        # pin the retired instance (and its full_params) in memory
-        apply_fn, static = self._apply_fn, self._apply_static
-
-        def fwd_fn(j, shape):
-            def build():
-                @jax.jit
-                def f(sp, x, aux):
-                    return apply_fn(*static, j, sp, x, aux)
-                return f
-            return self.step_cache.get(("fwd", self._cache_ns, j) + shape,
-                                       build)
+        def program(kind, j, mb):
+            key = self._keys(j, self._batch_shape(batches[mb]))[kind]
+            return self.step_cache.get(key, _not_compiled(key))
 
         def make_forward(j):
             def forward(mb, h_in=None):
@@ -290,7 +332,7 @@ class PipelinedModel:
                 else:
                     x = h_in
                 stashes[j][mb] = x
-                out = fwd_fn(j, shape_of(mb))(sparams[j], x, aux_of(mb))
+                out = program(0, j, mb)(sparams[j], x, aux_of(mb))
                 if j == c - 1:
                     stashes[j][mb] = (x, out)
                     loss_sum, w_sum = out
@@ -300,44 +342,19 @@ class PipelinedModel:
                 return out
             return forward
 
-        def bwd_fn(j, shape):
-            if j == c - 1:
-                def build_last():
-                    @jax.jit
-                    def b(sp, x, aux):
-                        def scalar(sp_, x_):
-                            loss_sum, _ = apply_fn(*static, j, sp_, x_, aux)
-                            return loss_sum
-                        (gp, gx) = jax.grad(scalar, argnums=(0, 1))(sp, x)
-                        return gp, gx
-                    return b
-                return self.step_cache.get(("bwd", self._cache_ns, j) + shape,
-                                           build_last)
-
-            def build():
-                @jax.jit
-                def b(sp, x, g_out, aux):
-                    _, vjp = jax.vjp(
-                        lambda sp_, x_: apply_fn(*static, j, sp_, x_, aux),
-                        sp, x)
-                    gp, gx = vjp(g_out)
-                    return gp, gx
-                return b
-            return self.step_cache.get(("bwd", self._cache_ns, j) + shape,
-                                       build)
-
         def make_backward(j):
             def backward(mb, g_out):
+                acc = result["stage_grads"][j]
+                if acc is None:
+                    acc = jax.tree.map(jnp.zeros_like, sparams[j])
+                bwd = program(1, j, mb)
                 if j == c - 1:
                     x, _ = stashes[j].pop(mb)
-                    gp, gx = bwd_fn(j, shape_of(mb))(sparams[j], x, aux_of(mb))
+                    acc, gx = bwd(sparams[j], x, aux_of(mb), acc)
                 else:
                     x = stashes[j].pop(mb)
-                    gp, gx = bwd_fn(j, shape_of(mb))(sparams[j], x, g_out,
-                                                     aux_of(mb))
-                acc = result["stage_grads"][j]
-                result["stage_grads"][j] = gp if acc is None else jax.tree.map(
-                    jnp.add, acc, gp)
+                    acc, gx = bwd(sparams[j], x, g_out, aux_of(mb), acc)
+                result["stage_grads"][j] = acc
                 if j == 0:
                     return None
                 return gx
@@ -352,6 +369,17 @@ class PipelinedModel:
         cbs = [StageCallbacks(make_forward(j), make_backward(j), make_step(j))
                for j in range(c)]
         return cbs, result
+
+
+def _struct(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+
+def _not_compiled(key):
+    def build():
+        raise RuntimeError(f"stage program {key} was not compiled before "
+                           "the stage threads started")
+    return build
 
 
 class EncDecPipelinedModel(PipelinedModel):

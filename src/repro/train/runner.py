@@ -595,11 +595,9 @@ class PlanAheadRunner:
                     continue
                 attempts = 0
 
-                scale = 1.0 / max(w_sum, 1.0)
-                grads = jax.tree.map(lambda g, scale=scale: g * scale,
-                                     grads)
                 params, opt, om = self.backend.optimizer_step(
-                    params, grads, opt, self.opt_cfg)
+                    params, grads, opt, self.opt_cfg,
+                    grad_scale=1.0 / max(w_sum, 1.0))
                 dt = time.perf_counter() - t0
                 if self.monitor is not None:
                     for rep in self._alive:

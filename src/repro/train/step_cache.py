@@ -46,6 +46,11 @@ class CompiledStepCache:
         return [k for k in self._fns
                 if isinstance(k, tuple) and k and k[0] == kind]
 
+    def entries(self, kind: str) -> list:
+        """The cached objects (jitted functions or compiled executables)
+        of one key kind, without counting a hit."""
+        return [self._fns[k] for k in self.keys_for(kind)]
+
     def count(self, kind: str) -> int:
         """Number of compiled entries for one key kind (see keys_for)."""
         return len(self.keys_for(kind))

@@ -15,12 +15,6 @@ SRC = REPO / "src"
 
 # sys.path comes from pyproject's `pythonpath = ["src", "."]` (or the
 # tier-1 command's PYTHONPATH=src).
-# hypothesis is an optional dependency (declared in pyproject.toml); in
-# hermetic environments without it, register the bundled stub before test
-# modules import `from hypothesis import given, ...`.
-from repro._compat import hypothesis_stub
-
-hypothesis_stub.install()
 
 
 @pytest.fixture(scope="session")

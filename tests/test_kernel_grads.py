@@ -245,11 +245,11 @@ def test_no_kv_repeat_or_per_head_position_repeat_in_jaxpr():
 def test_live_block_mask_matches_kernel_semantics():
     """Blocks the predicate marks dead contribute nothing: zeroing K/V in
     dead blocks leaves the output bit-identical."""
-    b, t, h, d = 1, 128, 1, 16
+    b, t, h, d = 1, 512, 1, 16
     q, k, v, _ = _inputs(b, t, t, h, d, h, jnp.float32)
     segs = _segments(t, "packed")[:1]
     pos = _positions(segs)
-    bq = bk = 32
+    bq = bk = 128                              # the smallest legal TPU tile
     mask = live_block_mask(pos, pos, segs, segs, causal=True,
                            block_q=bq, block_kv=bk)
     assert mask.shape == (1, t // bq, t // bk)
@@ -281,9 +281,9 @@ def test_block_skip_survives_nan_in_dead_blocks():
     gated the compute in fwd, dq, AND dk/dv. (The analytic live-block
     fraction in bench_attention mirrors the predicate; this is the test
     that the kernels enforce it.)"""
-    b, t, h, d = 2, 128, 2, 16
-    bq = 32
-    pad_from = 96                              # block-aligned padding start
+    b, t, h, d = 2, 512, 2, 16
+    bq = 128                                   # the smallest legal TPU tile
+    pad_from = 384                             # block-aligned padding start
     q, k, v, ct = _inputs(b, t, t, h, d, h, jnp.float32)
     seg = np.zeros((b, t), np.int32)
     seg[:, pad_from:] = -1

@@ -60,7 +60,7 @@ def test_pure_dp_mode_loss_equality():
     out = run_subprocess_devices("""
 import dataclasses, jax, jax.numpy as jnp
 from repro.configs.base import get_arch, reduced
-from repro.dist.sharding import pure_dp
+from repro.dist.sharding import pure_dp, set_mesh
 from repro.models import model as MD
 cfg = reduced(get_arch("gemma2-2b"))
 params = MD.init_params(jax.random.PRNGKey(0), cfg)
@@ -73,7 +73,7 @@ b = {"tokens": jax.random.randint(k1,(B,S),0,cfg.vocab),
      "segment_ids": jnp.zeros((B,S),jnp.int32)}
 l0, _ = MD.loss_fn(params, b, cfg)
 mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
-with jax.set_mesh(mesh), pure_dp(True):
+with set_mesh(mesh), pure_dp(True):
     l1, _ = jax.jit(lambda p, b: MD.loss_fn(p, b, cfg))(params, b)
 err = abs(float(l0) - float(l1))
 assert err < 2e-3, (float(l0), float(l1))

@@ -231,3 +231,18 @@ def test_planner_pool_process_backend():
             assert store.fetch(i, timeout=60).n_stages == 2
     finally:
         pool.shutdown()
+
+
+def test_planner_process_workers_run_on_cpu(monkeypatch):
+    """Planner worker processes never reach for a device: they start with
+    JAX_PLATFORMS=cpu even when the parent's environment has none, so on
+    a TPU host they cannot take the chip the parent holds."""
+    import os
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    pool = PlannerPool(InstructionStore(), n_workers=1, use_processes=True)
+    try:
+        assert pool.pool.submit(os.getenv, "JAX_PLATFORMS").result(
+            timeout=300) == "cpu"
+    finally:
+        pool.shutdown()

@@ -128,6 +128,30 @@ def test_runner_config_routes_process_fault_domain(monkeypatch):
     assert seen["pcfg"].dp_size == 2
 
 
+def test_process_cluster_refuses_a_tpu_platform(monkeypatch, tmp_path):
+    """On a TPU host every replica process would need the one chip: the
+    cluster must refuse before it spawns a worker or makes a run dir."""
+    import multiprocessing
+
+    import jax
+
+    def no_spawn(*a, **k):
+        raise AssertionError("spawned a process on a TPU platform")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+    rundir = tmp_path / "run"
+    cm = AnalyticCostModel(CFG, n_stages=1)
+    pcfg = PlannerConfig(n_stages=1, dp_size=2, d_model=CFG.d_model,
+                         palette=PAL)
+    with pytest.raises(RuntimeError, match="CPU test harness"):
+        run_process_cluster(CFG, cm, pcfg, RunnerConfig(n_iters=1),
+                            MultiTaskStream(STREAM_CFG),
+                            ccfg=ClusterConfig(n_replicas=2,
+                                               rundir=str(rundir)))
+    assert not rundir.exists()
+
+
 def test_make_backend_process_points_at_cluster():
     from repro.dist.backend import make_backend
 

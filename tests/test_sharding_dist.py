@@ -23,12 +23,13 @@ def test_pure_dp_spec_roundtrip_one_device_mesh():
     must be identity, and values must round-trip through them unchanged."""
     import jax
     import jax.numpy as jnp
-    from repro.dist.sharding import ambient_mesh, axis_size, pure_dp, shard
+    from repro.dist.sharding import (ambient_mesh, axis_size, pure_dp,
+                                     set_mesh, shard)
 
     mesh = jax.make_mesh((1,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     x = np.arange(32, dtype=np.float32).reshape(4, 8)
-    with jax.set_mesh(mesh), pure_dp(True):
+    with set_mesh(mesh), pure_dp(True):
         assert ambient_mesh() is mesh
         assert axis_size("dp") == 1 and axis_size("tp") == 1
         # dp resolves to the whole (trivial) mesh; tp resolves to nothing
@@ -60,8 +61,9 @@ batch = {
   "segment_ids": jnp.zeros((B,S), jnp.int32),
 }
 loss0, _ = MD.loss_fn(params, batch, cfg)
+from repro.dist.sharding import set_mesh
 mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
-with jax.set_mesh(mesh):
+with set_mesh(mesh):
     loss1, _ = jax.jit(lambda p, b: MD.loss_fn(p, b, cfg))(params, batch)
 err = abs(float(loss0) - float(loss1))
 assert err < 2e-3, (float(loss0), float(loss1))
@@ -82,8 +84,9 @@ key = jax.random.PRNGKey(0)
 p = L.init_moe(key, cfg)
 x = jax.random.normal(jax.random.fold_in(key,1), (4, 16, cfg.d_model), jnp.float32)
 y_ref, _ = L.moe_fwd(p, x, cfg)
+from repro.dist.sharding import set_mesh
 mesh = jax.make_mesh((2, 4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,)*2)
-with jax.set_mesh(mesh):
+with set_mesh(mesh):
     y_sm, _ = jax.jit(lambda p, x: L.moe_fwd(p, x, cfg))(p, x)
 np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_sm), atol=2e-5, rtol=2e-5)
 print("MOE_OK")
