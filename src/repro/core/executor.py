@@ -41,6 +41,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
+from repro.core import spans
 from repro.core.instructions import ExecutionPlan, Instr, Op
 
 _POLL_S = 0.05                       # abort-observation latency bound
@@ -184,6 +185,8 @@ class StageExecutor:
         self.comm_pos: Optional[Instr] = None
         self.compute_done = False
         self.comm_done = False
+        self._fwd_span = spans.stage(stage, "fwd")
+        self._bwd_span = spans.stage(stage, "bwd")
 
     # ------------------------------ comm thread ------------------------
     @staticmethod
@@ -244,13 +247,14 @@ class StageExecutor:
         with self._lock:
             ev = self.recv_done.setdefault(tag, threading.Event())
         deadline = time.monotonic() + self.timeout
-        while not ev.wait(_POLL_S):
-            if self.abort.is_set():
-                raise PipelineAborted(
-                    f"stage {self.stage}: wait on {tag} aborted (peer failed)")
-            if time.monotonic() > deadline:
-                raise DeadlockError(
-                    f"stage {self.stage}: wait on {tag} timed out")
+        with spans.span(spans.RECV_WAIT):
+            while not ev.wait(_POLL_S):
+                if self.abort.is_set():
+                    raise PipelineAborted(f"stage {self.stage}: wait on "
+                                          f"{tag} aborted (peer failed)")
+                if time.monotonic() > deadline:
+                    raise DeadlockError(
+                        f"stage {self.stage}: wait on {tag} timed out")
         with self._lock:
             return self.recv_buf.pop(tag)
 
@@ -273,12 +277,13 @@ class StageExecutor:
                     with self._lock:
                         self.recv_buf[("grad_ready", ins.micro_batch)] = g
                 elif ins.op == Op.FORWARD:
-                    if self.stage == 0:
-                        h_out = self.cb.forward(ins.micro_batch)
-                    else:
+                    h_in = ()
+                    if self.stage > 0:
                         with self._lock:
-                            h_in = self.recv_buf.pop(("act_ready", ins.micro_batch))
-                        h_out = self.cb.forward(ins.micro_batch, h_in)
+                            h_in = (self.recv_buf.pop(
+                                ("act_ready", ins.micro_batch)),)
+                    with spans.span(self._fwd_span, mb=ins.micro_batch):
+                        h_out = self.cb.forward(ins.micro_batch, *h_in)
                     if self.stage + 1 < self.n_stages:
                         with self._lock:
                             self.send_buf[("act", ins.micro_batch)] = h_out
@@ -288,7 +293,8 @@ class StageExecutor:
                             g_out = self.recv_buf.pop(("grad_ready", ins.micro_batch))
                     else:
                         g_out = None
-                    g_in = self.cb.backward(ins.micro_batch, g_out)
+                    with spans.span(self._bwd_span, mb=ins.micro_batch):
+                        g_in = self.cb.backward(ins.micro_batch, g_out)
                     if self.stage > 0:
                         with self._lock:
                             self.send_buf[("grad", ins.micro_batch)] = g_in
@@ -351,6 +357,10 @@ class PipelineExecutor:
     def run(self):
         if self.strict:
             reject_bad_plan(self.plan, "PipelineExecutor")
+        with spans.span(spans.PIPELINE):
+            self._run()
+
+    def _run(self):
         c = self.plan.n_stages
         abort = threading.Event()
         channels = {}

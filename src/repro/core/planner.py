@@ -26,7 +26,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.core import comm_plan, microbatch, schedule as sched
+from repro.core import comm_plan, microbatch, schedule as sched, spans
 from repro.core.cost_model import CostModel
 from repro.core.instructions import (ExecutionPlan, InstructionStore,
                                      MicroBatchSpec, RecomputePolicy)
@@ -137,6 +137,21 @@ def plan_replica(
 
 def plan_iteration(lengths, cost: CostModel, pcfg: PlannerConfig,
                    recompute: RecomputePolicy = RecomputePolicy.FULL) -> IterationPlan:
+    """One iteration's plan, under a ``dynapipe.plan`` span whose args are
+    its micro-batch count, real and padded tokens and predicted time."""
+    with spans.span(spans.PLAN) as sp:
+        it_plan = _plan_iteration(lengths, cost, pcfg, recompute)
+        sp.set_metadata(
+            n_micro=len(it_plan.micro_batches),
+            real_tokens=int(np.sum(lengths)),
+            padded_tokens=int(sum(m.padded_tokens
+                                  for m in it_plan.micro_batches)),
+            predicted_ms=it_plan.predicted_iteration_time * 1e3)
+    return it_plan
+
+
+def _plan_iteration(lengths, cost: CostModel, pcfg: PlannerConfig,
+                    recompute: RecomputePolicy) -> IterationPlan:
     t0 = time.perf_counter()
     order = microbatch.order_samples(lengths, pcfg.ordering)
     L = microbatch._as2d(lengths)[order]

@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.core import spans
 from repro.core.executor import (PipelineExecutor, StageCallbacks,
                                 reject_bad_plan)
 from repro.core.instructions import ExecutionPlan, Instr, Op
@@ -121,10 +122,10 @@ def _scaled_adamw(opt_cfg, *, donate: bool):
     """jit of ``adamw_update`` over ``grads * scale``. A Python-float scale
     is weakly typed, so bf16 grads scale in bf16 exactly as an eager
     ``g * scale`` would."""
-    def step(params, grads, opt_state, scale):
+    def adamw_step(params, grads, opt_state, scale):
         grads = jax.tree.map(lambda g: g * scale, grads)
         return adamw_update(params, grads, opt_state, opt_cfg)
-    return jax.jit(step, donate_argnums=(0, 1, 2) if donate else ())
+    return jax.jit(adamw_step, donate_argnums=(0, 1, 2) if donate else ())
 
 
 def _timed_callbacks(cbs: list[StageCallbacks], records: list, lock):
@@ -239,17 +240,25 @@ class ThreadsBackend(ExecutionBackend):
 
         if self.pm is not None:
             pm = self.pm
-            pm.set_params(params)
-            cbs, result = pm.make_callbacks(plan, batches)
-            records: list = []
-            if collect_timings:
-                cbs = _timed_callbacks(cbs, records, threading.Lock())
+            with spans.span(spans.STAGE_SETUP):
+                pm.set_params(params)
+                cbs, result = pm.make_callbacks(plan, batches)
+                records: list = []
+                if collect_timings:
+                    cbs = _timed_callbacks(cbs, records, threading.Lock())
             PipelineExecutor(plan, cbs, timeout=timeout, hook=hook).run()
             del cbs     # drops the stage param slices before the merge
-            grads = pm.merge_stage_grads(result["stage_grads"])
+            with spans.span(spans.GRAD_MERGE):
+                grads = pm.merge_stage_grads(result["stage_grads"])
             return BackendResult(grads, result["loss_sum"],
                                  result["weight_sum"], records)
 
+        with spans.span(spans.PIPELINE):
+            return self._execute_sequential(batches, params, hook,
+                                            collect_timings)
+
+    def _execute_sequential(self, batches, params, hook,
+                            collect_timings: bool) -> BackendResult:
         grads, loss_sum, w_sum = None, 0.0, 0.0
         timings: list = []
         for mb_id in sorted(batches):
@@ -258,11 +267,13 @@ class ThreadsBackend(ExecutionBackend):
                 # stage-0 forward per micro-batch so stage-0 faults (and
                 # stragglers) inject identically
                 hook(0, Instr(Op.FORWARD, mb_id))
-            b = {k: jnp.asarray(v) for k, v in batches[mb_id].items()}
+            with spans.span(spans.DEVICE_PUT):
+                b = {k: jnp.asarray(v) for k, v in batches[mb_id].items()}
             t0 = time.perf_counter()
             ls, ws, g = self._grad_fn(self._batch_shape(b))(params, b)
-            loss_sum += float(ls)    # float() syncs: t0..here is real compute
-            w_sum += float(ws)
+            with spans.span(spans.LOSS_SYNC):
+                loss_sum += float(ls)  # float() syncs: t0..here is compute
+                w_sum += float(ws)
             if collect_timings:
                 timings.append(("total", mb_id, time.perf_counter() - t0))
             grads = g if grads is None else jax.tree.map(jnp.add, grads, g)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core import spans
 from repro.core.executor import StageCallbacks
 from repro.core.instructions import ExecutionPlan
 from repro.models import layers as L
@@ -63,14 +64,14 @@ def build_grad_step(cfg: ArchConfig, impl: Optional[str] = None):
     ``REPRO_KERNEL_IMPL`` env override)."""
 
     @jax.jit
-    def grad_mb(p, batch):
+    def grad_step(p, batch):
         def f(p_):
             h, _, _ = MD.forward(p_, batch, cfg, mode="train", impl=impl)
             return _xent_sum(p_.get("head", p_.get("embed")), h,
                              batch["labels"], batch["loss_weights"], cfg)
         (loss_sum, w_sum), g = jax.value_and_grad(f, has_aux=True)(p)
         return loss_sum, w_sum, g
-    return grad_mb
+    return grad_step
 
 
 def build_encdec_grad_step(cfg: ArchConfig, impl: Optional[str] = None):
@@ -79,7 +80,7 @@ def build_encdec_grad_step(cfg: ArchConfig, impl: Optional[str] = None):
     The enc-dec analogue of :func:`build_grad_step`."""
 
     @jax.jit
-    def grad_mb(p, batch):
+    def grad_step(p, batch):
         def f(p_):
             hd = T.encdec_fwd(
                 p_, batch["enc_tokens"], batch["dec_tokens"], cfg,
@@ -91,7 +92,7 @@ def build_encdec_grad_step(cfg: ArchConfig, impl: Optional[str] = None):
                              batch["loss_weights"], cfg)
         (loss_sum, w_sum), g = jax.value_and_grad(f, has_aux=True)(p)
         return loss_sum, w_sum, g
-    return grad_mb
+    return grad_step
 
 
 def _stage_apply(cfg: ArchConfig, k: int, n_stages: int, impl, j: int,
@@ -250,6 +251,9 @@ class PipelinedModel:
         shapes, and the cache holds the executables: the stage threads only
         ever run compiled code. The backward programs take the stage's grad
         accumulator as a donated argument and return it updated in place.
+        Programs are named ``stage{j}_fwd`` / ``stage{j}_bwd``, so a device
+        trace's ``XLA Modules`` line reads ``jit_stage{j}_fwd``; each
+        compile runs under a ``dynapipe.compile`` span.
         """
         c = self.n_stages
         firsts: dict[tuple, int] = {}
@@ -272,8 +276,8 @@ class PipelinedModel:
 
                 def fwd(sp_, x_, aux_, j=j):
                     return apply_fn(*static, j, sp_, x_, aux_)
-                self.step_cache.get(fwd_key, lambda: jax.jit(fwd).lower(
-                    sp, x, aux).compile())
+                self.step_cache.get(fwd_key, lambda: _compile(
+                    fwd, j, "fwd", shape, (sp, x, aux)))
                 if j == c - 1:
                     def bwd(sp_, x_, aux_, acc, j=j):
                         def scalar(p, x2):
@@ -290,8 +294,9 @@ class PipelinedModel:
                         return jax.tree.map(jnp.add, acc, gp), gx
                     x_next = jax.eval_shape(fwd, sp, x, aux)
                     args = (sp, x, x_next, aux, sp)
-                self.step_cache.get(bwd_key, lambda: jax.jit(
-                    bwd, donate_argnums=len(args) - 1).lower(*args).compile())
+                self.step_cache.get(bwd_key, lambda: _compile(
+                    bwd, j, "bwd", shape, args,
+                    donate_argnums=len(args) - 1))
                 if j < c - 1:
                     x = x_next
 
@@ -328,7 +333,9 @@ class PipelinedModel:
         def make_forward(j):
             def forward(mb, h_in=None):
                 if j == 0:
-                    x = {k: jnp.asarray(v) for k, v in batches[mb].items()}
+                    with spans.span(spans.DEVICE_PUT):
+                        x = {k: jnp.asarray(v)
+                             for k, v in batches[mb].items()}
                 else:
                     x = h_in
                 stashes[j][mb] = x
@@ -336,8 +343,9 @@ class PipelinedModel:
                 if j == c - 1:
                     stashes[j][mb] = (x, out)
                     loss_sum, w_sum = out
-                    result["loss_sum"] += float(loss_sum)
-                    result["weight_sum"] += float(w_sum)
+                    with spans.span(spans.LOSS_SYNC):
+                        result["loss_sum"] += float(loss_sum)
+                        result["weight_sum"] += float(w_sum)
                     return None
                 return out
             return forward
@@ -373,6 +381,16 @@ class PipelinedModel:
 
 def _struct(x):
     return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+
+def _compile(fn, j: int, kind: str, shape: tuple, args, **jit_kw):
+    """Ahead-of-time compile of stage ``j``'s ``kind`` program, named
+    ``stage{j}_{kind}`` (the name is all that differs from a plain
+    ``jax.jit(fn)``: the compiled code is the same)."""
+    fn.__name__ = fn.__qualname__ = f"stage{j}_{kind}"
+    with spans.span(spans.COMPILE, stage=j, kind=kind,
+                    shape="x".join(map(str, shape))):
+        return jax.jit(fn, **jit_kw).lower(*args).compile()
 
 
 def _not_compiled(key):

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core import spans
 from repro.core.cost_model import CostModel, OnlineCalibrator
 from repro.core.executor import PipelineError
 from repro.core.instructions import ExecutionPlan, InstructionStore
@@ -317,47 +318,49 @@ class PlanAheadRunner:
         rcfg = self.rcfg
         if rcfg.synchronous:
             gb = self.stream.batch(it)
-            t0 = time.perf_counter()
-            if self.chaos is not None:
-                ev = self.chaos.take_planner_fault(it)
-                if ev is not None and stats is not None:
-                    # inline planning: a dead planner is just re-run inline
-                    stats.faults += 1
-                    stats.recoveries.append(
-                        {"iter": it, "kind": "planner_replanned",
-                         "fault": ev.describe()})
-            it_plan = plan_iteration(self._plan_lengths(gb), self.cost,
-                                     self._pcfg_now())
-            self.store.push(it, it_plan.replica_plans[0])
-            plan = self.store.fetch(it, timeout=rcfg.plan_timeout)
-            wait = time.perf_counter() - t0
-        else:
-            gb = self._pending.pop(it)
-            t0 = time.perf_counter()
-            it_plan = None
-            for attempt in range(rcfg.max_retries + 1):
-                fut = self._futures.pop(it)
-                try:
-                    it_plan = fut.result(timeout=rcfg.plan_timeout)
-                    break
-                except (TimeoutError, cf.TimeoutError, cf.CancelledError,
-                        cf.BrokenExecutor, InjectedFault) as e:
-                    if attempt >= rcfg.max_retries:
-                        raise PipelineError(
-                            f"plan for iteration {it} failed after "
-                            f"{attempt + 1} attempts: {e!r}") from e
-                    if stats is not None:
+            with spans.span(spans.PLAN_WAIT):
+                t0 = time.perf_counter()
+                if self.chaos is not None:
+                    ev = self.chaos.take_planner_fault(it)
+                    if ev is not None and stats is not None:
+                        # inline planning: a dead planner is just re-run
                         stats.faults += 1
                         stats.recoveries.append(
-                            {"iter": it, "kind": "planner_resubmit",
-                             "fault": repr(e)})
-                    if isinstance(e, cf.BrokenExecutor):
-                        self._reset_pool()
-                    time.sleep(rcfg.retry_backoff_s * (attempt + 1))
-                    self._submit(it)
-                    self._pending.pop(it, None)  # gb already in hand
-            plan = self.store.fetch(it, timeout=rcfg.plan_timeout)
-            wait = time.perf_counter() - t0
+                            {"iter": it, "kind": "planner_replanned",
+                             "fault": ev.describe()})
+                it_plan = plan_iteration(self._plan_lengths(gb), self.cost,
+                                         self._pcfg_now())
+                self.store.push(it, it_plan.replica_plans[0])
+                plan = self.store.fetch(it, timeout=rcfg.plan_timeout)
+                wait = time.perf_counter() - t0
+        else:
+            gb = self._pending.pop(it)
+            with spans.span(spans.PLAN_WAIT):
+                t0 = time.perf_counter()
+                it_plan = None
+                for attempt in range(rcfg.max_retries + 1):
+                    fut = self._futures.pop(it)
+                    try:
+                        it_plan = fut.result(timeout=rcfg.plan_timeout)
+                        break
+                    except (TimeoutError, cf.TimeoutError, cf.CancelledError,
+                            cf.BrokenExecutor, InjectedFault) as e:
+                        if attempt >= rcfg.max_retries:
+                            raise PipelineError(
+                                f"plan for iteration {it} failed after "
+                                f"{attempt + 1} attempts: {e!r}") from e
+                        if stats is not None:
+                            stats.faults += 1
+                            stats.recoveries.append(
+                                {"iter": it, "kind": "planner_resubmit",
+                                 "fault": repr(e)})
+                        if isinstance(e, cf.BrokenExecutor):
+                            self._reset_pool()
+                        time.sleep(rcfg.retry_backoff_s * (attempt + 1))
+                        self._submit(it)
+                        self._pending.pop(it, None)  # gb already in hand
+                plan = self.store.fetch(it, timeout=rcfg.plan_timeout)
+                wait = time.perf_counter() - t0
         self.store.evict_below(it)  # executed plans are dead; keep RSS flat
         return gb, plan, it_plan, wait, it_plan.planning_seconds
 
@@ -379,9 +382,10 @@ class PlanAheadRunner:
         """One replica's plan -> (grads, loss_sum, weight_sum)."""
         if not plan.micro_batches:
             return None, 0.0, 0.0   # idle replica (fewer micro-batches than dp)
-        batches = {m.mb_id: materialize_micro_batch(
-                       m, gb.tokens, lengths=gb.lengths)
-                   for m in plan.micro_batches}
+        with spans.span(spans.MATERIALIZE):
+            batches = {m.mb_id: materialize_micro_batch(
+                           m, gb.tokens, lengths=gb.lengths)
+                       for m in plan.micro_batches}
         hook = (self.chaos.executor_hook(it, replica=rep)
                 if self.chaos is not None else None)
         res = self.backend.execute_plan(
@@ -401,6 +405,40 @@ class PlanAheadRunner:
                 else:
                     self._calibrator.observe_total(m.mbs, seq, secs)
         return res.grads, res.loss_sum, res.weight_sum
+
+    def _execute_replicas(self, it: int, plan: ExecutionPlan, it_plan, gb,
+                          params):
+        """Every surviving replica's plan, executed here (one process stands
+        in for the DP group), with their grads merged: the full-batch
+        gradient, and so the loss trajectory, does not depend on how the
+        planner split work across replicas. Returns (grads, loss_sum,
+        weight_sum, per-replica seconds)."""
+        if self._encdec and any(not isinstance(m.seq, (tuple, list))
+                                for m in plan.micro_batches):
+            raise ValueError(
+                "enc-dec model got a decoder-only micro-batch: the stream "
+                "must carry (enc, dec) lengths with dec > 0 for every "
+                "sample (use encdec_fraction=1.0)")
+        grads, loss_sum, w_sum = None, 0.0, 0.0
+        replica_s: dict[int, float] = {}
+        for pos, rplan in enumerate(it_plan.replica_plans):
+            rep = self._alive[pos] if pos < len(self._alive) else pos
+            # replica 0 executes the store-roundtripped plan (keeps the
+            # serialization path on the hot loop); others roundtrip locally
+            # for identical semantics
+            xplan = plan if pos == 0 else \
+                ExecutionPlan.from_json(rplan.to_json())
+            rt0 = time.perf_counter()
+            g, ls, ws = self._execute_replica(it, rep, xplan, gb, params)
+            replica_s[rep] = time.perf_counter() - rt0
+            loss_sum += ls
+            w_sum += ws
+            if g is not None and grads is not None:
+                with spans.span(spans.GRAD_MERGE):
+                    grads = jax.tree.map(jnp.add, grads, g)
+            elif g is not None:
+                grads = g
+        return grads, loss_sum, w_sum, replica_s
 
     # ------------------------- recovery side ---------------------------
     def _drain(self) -> None:
@@ -535,70 +573,58 @@ class PlanAheadRunner:
         attempts = 0
         try:
             while it < end:
-                t0 = time.perf_counter()
-                try:
-                    if self.elastic is not None \
-                            and self.monitor.alive() != self._alive:
+                with spans.span(spans.ITERATION, it=it) as it_span:
+                    t0 = time.perf_counter()
+                    try:
+                        if self.elastic is not None \
+                                and self.monitor.alive() != self._alive:
+                            t_rec = time.perf_counter()
+                            self._topology_sweep(it, stats)
+                            stats.recovery_s += time.perf_counter() - t_rec
+                        if not rcfg.synchronous \
+                                and it + rcfg.lookahead < end \
+                                and (it + rcfg.lookahead) not in self._futures:
+                            with spans.span(spans.SUBMIT):
+                                self._submit(it + rcfg.lookahead)
+                        gb, plan, it_plan, wait_s, planning_s = \
+                            self._obtain(it, stats)
+                        micro = [m for rp in it_plan.replica_plans
+                                 for m in rp.micro_batches]
+                        n_micro = len(micro)
+                        padded = sum(m.mbs * (sum(m.seq) if isinstance(
+                            m.seq, (tuple, list)) else m.seq) for m in micro)
+                        it_span.set_metadata(
+                            real_tokens=gb.total_tokens,
+                            padded_tokens=int(padded), n_micro=n_micro,
+                            plan_wait_ms=wait_s * 1e3,
+                            predicted_compute_ms=1e3 * sum(
+                                m.t_fwd + m.t_bwd for m in micro))
+                        grads, loss_sum, w_sum, replica_s = \
+                            self._execute_replicas(it, plan, it_plan, gb,
+                                                   params)
+                    except (PipelineError, InjectedFault) as e:
+                        stats.faults += 1
+                        attempts += 1
+                        if attempts > rcfg.max_retries:
+                            # retry budget exhausted — the BaseException
+                            # handler below writes the emergency checkpoint
+                            raise
                         t_rec = time.perf_counter()
-                        self._topology_sweep(it, stats)
+                        params, opt, it = self._recover(it, e, params, opt,
+                                                        stats)
                         stats.recovery_s += time.perf_counter() - t_rec
-                    if not rcfg.synchronous and it + rcfg.lookahead < end \
-                            and (it + rcfg.lookahead) not in self._futures:
-                        self._submit(it + rcfg.lookahead)
-                    gb, plan, it_plan, wait_s, planning_s = \
-                        self._obtain(it, stats)
+                        continue
+                    attempts = 0
 
-                    if self._encdec and any(
-                            not isinstance(m.seq, (tuple, list))
-                            for m in plan.micro_batches):
-                        raise ValueError(
-                            "enc-dec model got a decoder-only micro-batch: "
-                            "the stream must carry (enc, dec) lengths with "
-                            "dec > 0 for every sample (use "
-                            "encdec_fraction=1.0)")
-
-                    # every surviving replica's plan executes here (single
-                    # process stands in for the DP group) and the grads
-                    # merge, so the full-batch gradient — and the loss
-                    # trajectory — is invariant to how the planner split
-                    # work across replicas
-                    grads, loss_sum, w_sum = None, 0.0, 0.0
-                    replica_s: dict[int, float] = {}
-                    for pos, rplan in enumerate(it_plan.replica_plans):
-                        rep = (self._alive[pos] if pos < len(self._alive)
-                               else pos)
-                        # replica 0 executes the store-roundtripped plan
-                        # (keeps the serialization path on the hot loop);
-                        # others roundtrip locally for identical semantics
-                        xplan = plan if pos == 0 else \
-                            ExecutionPlan.from_json(rplan.to_json())
-                        rt0 = time.perf_counter()
-                        g, ls, ws = self._execute_replica(
-                            it, rep, xplan, gb, params)
-                        replica_s[rep] = time.perf_counter() - rt0
-                        loss_sum += ls
-                        w_sum += ws
-                        if g is not None:
-                            grads = g if grads is None else jax.tree.map(
-                                jnp.add, grads, g)
-                except (PipelineError, InjectedFault) as e:
-                    stats.faults += 1
-                    attempts += 1
-                    if attempts > rcfg.max_retries:
-                        # retry budget exhausted — the BaseException handler
-                        # below writes the emergency checkpoint
-                        raise
-                    t_rec = time.perf_counter()
-                    params, opt, it = self._recover(it, e, params, opt,
-                                                    stats)
-                    stats.recovery_s += time.perf_counter() - t_rec
-                    continue
-                attempts = 0
-
-                params, opt, om = self.backend.optimizer_step(
-                    params, grads, opt, self.opt_cfg,
-                    grad_scale=1.0 / max(w_sum, 1.0))
-                dt = time.perf_counter() - t0
+                    with spans.span(spans.OPTIMIZER):
+                        params, opt, om = self.backend.optimizer_step(
+                            params, grads, opt, self.opt_cfg,
+                            grad_scale=1.0 / max(w_sum, 1.0))
+                    # the step's one sync: it waits for AdamW, so the
+                    # iteration's time (and its span) include the update
+                    with spans.span(spans.STEP_SYNC):
+                        grad_norm = float(om["grad_norm"])
+                    dt = time.perf_counter() - t0
                 if self.monitor is not None:
                     for rep in self._alive:
                         if self.chaos is not None \
@@ -609,18 +635,10 @@ class PlanAheadRunner:
                     if isinstance(self.monitor.clock, LogicalClock):
                         self.monitor.clock.advance(1.0)
 
-                padded = sum(
-                    m.mbs * (sum(m.seq) if isinstance(m.seq, (tuple, list))
-                             else m.seq)
-                    for rp in it_plan.replica_plans
-                    for m in rp.micro_batches)
-                n_micro = sum(len(rp.micro_batches)
-                              for rp in it_plan.replica_plans)
                 loss = loss_sum / max(w_sum, 1.0)
                 history.append({
                     "iter": it, "loss": loss, "time_s": dt,
-                    "n_micro": n_micro,
-                    "grad_norm": float(om["grad_norm"]),
+                    "n_micro": n_micro, "grad_norm": grad_norm,
                     "plan_wait_s": wait_s, "planning_s": planning_s,
                     "tokens": gb.total_tokens, "padded_tokens": int(padded),
                 })
