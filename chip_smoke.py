@@ -164,7 +164,8 @@ def train(cfg, *, iters=ITERS, global_tokens=GLOBAL_TOKENS, max_len=MAX_LEN,
         "faults": stats.faults,
         "recoveries": len(stats.recoveries),
         "memory": _memory(jax.devices()[0]),
-        "stage_programs": [p.as_text() for kind in ("fwd", "bwd")
+        "stage_programs": [p.as_text()
+                           for kind in ("fwd", "bwd", "fwd_bwd")
                            for p in runner.step_cache.entries(kind)],
     }
     del params, runner        # free the training state before the check

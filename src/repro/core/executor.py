@@ -27,10 +27,13 @@ deadline) reports which stage is stuck on which instruction.
 ``hook(stage, instr)`` on the compute stream — the fault-injection point
 used by :mod:`repro.dist.chaos` (delay = straggler, raise = stage crash).
 
-Backward passes recompute the stage forward (activation checkpointing at
-stage granularity) via ``jax.vjp`` — matching RecomputePolicy.FULL; the only
+With ``train/pipeline_adapter.py``'s callbacks, the backward of every stage
+but the last recomputes the stage forward (activation checkpointing at stage
+granularity) via ``jax.vjp`` — matching RecomputePolicy.FULL; the only
 stashed state per in-flight micro-batch is its stage input, which is what the
-planner's memory model charges.
+planner's memory model charges. The last stage's FORWARD runs forward and
+backward in one program and stashes the input gradient (the size of its
+input); its BACKWARD sends that gradient on.
 """
 from __future__ import annotations
 

@@ -8,7 +8,9 @@ device value, so none adds a sync.
 
 Main (runner) thread, inside one ``dynapipe.iteration`` per loop pass:
 ``submit``, ``plan_wait``, ``materialize``, ``stage_setup`` (with a
-``compile`` per stage program compiled there), ``pipeline``,
+``compile`` per stage program compiled there, with args ``stage``,
+``kind`` (``fwd``, ``bwd`` or the last stage's ``fwd_bwd``), ``shape`` and
+``remat``, 1 where the program checkpoints each period), ``pipeline``,
 ``grad_merge``, ``optimizer`` (dispatch) and ``step_sync``; ``pipeline`` is
 ``PipelineExecutor.run``, or the sequential path's micro-batch loop (with
 its own ``device_put`` and ``loss_sync``). Stage compute threads:

@@ -131,27 +131,26 @@ def _scaled_adamw(opt_cfg, *, donate: bool):
 def _timed_callbacks(cbs: list[StageCallbacks], records: list, lock):
     """Wrap every stage's fwd/bwd with wall timers (block_until_ready so
     dispatch isn't mistaken for compute). Records ("f"/"b", mb_id, s)
-    under ``lock`` — callbacks run on stage threads."""
-    def wrap(cb: StageCallbacks) -> StageCallbacks:
-        def fwd(mb_id, *a):
+    under ``lock`` — callbacks run on stage threads. The last stage's
+    forward runs its forward and backward in one program, so it records
+    ("total", mb_id, s) and its backward, which only hands the stashed
+    gradient on, records nothing."""
+    def timed(call, kind):
+        def run(mb_id, *a):
             t0 = time.perf_counter()
-            out = cb.forward(mb_id, *a)
+            out = call(mb_id, *a)
             if out is not None:
                 jax.block_until_ready(out)
             with lock:
-                records.append(("f", mb_id, time.perf_counter() - t0))
+                records.append((kind, mb_id, time.perf_counter() - t0))
             return out
+        return run
 
-        def bwd(mb_id, g):
-            t0 = time.perf_counter()
-            out = cb.backward(mb_id, g)
-            if out is not None:
-                jax.block_until_ready(out)
-            with lock:
-                records.append(("b", mb_id, time.perf_counter() - t0))
-            return out
-        return StageCallbacks(fwd, bwd, cb.step)
-    return [wrap(cb) for cb in cbs]
+    last = len(cbs) - 1
+    return [StageCallbacks(timed(cb.forward, "total" if j == last else "f"),
+                           cb.backward if j == last
+                           else timed(cb.backward, "b"), cb.step)
+            for j, cb in enumerate(cbs)]
 
 
 class ThreadsBackend(ExecutionBackend):

@@ -187,9 +187,10 @@ def pipelined_grads(
     order the caller stacked the micro-batches, i.e. the §6 comm-plan
     injection order), then an equal-length backward ring in the reverse
     direction: per tick, ``jax.vjp`` recomputes the stage forward from the
-    stashed stage input (stage-granular activation checkpointing, the same
-    policy as the host plane's ``train/pipeline_adapter.py``) and the
-    incoming cotangent ppermutes from stage ``s + 1`` to ``s``.
+    stashed stage input (stage-granular activation checkpointing, the
+    policy the host plane's ``train/pipeline_adapter.py`` keeps for every
+    stage but the last) and the incoming cotangent ppermutes from stage
+    ``s + 1`` to ``s``.
 
     Args:
       stage_fn: ``stage_fn(stage_weights, shared, h_buf, batch, stage, last)

@@ -3,9 +3,16 @@
 Splits a model's scan-over-periods parameter stack into ``n_stages``
 contiguous period groups; stage 0 additionally owns the embedding (+
 modality adapters), the last stage owns the final norm and LM head.
-Backward recomputes the stage forward via ``jax.vjp`` (stage-granular
-activation checkpointing), so the only per-micro-batch stash is the stage
-input — the quantity the planner's memory model charges.
+Every stage but the last runs a forward program and, later, a backward
+program that recomputes the stage forward via ``jax.vjp`` from the stashed
+stage input (stage-granular activation checkpointing), so the only
+per-micro-batch stash is the stage input — the quantity the planner's
+memory model charges. The last stage trains each micro-batch in one
+``value_and_grad`` program when its forward runs: the loss needs the
+primal output anyway, so a separate forward program would only run the
+stage twice. It stashes the input gradient, the size of its input, until
+the schedule's backward sends it. Within a stage program, periods are
+checkpointed only where a stage holds more than one (``_period_remat``).
 
 Tied embeddings are duplicated on stages 0 and c-1; their gradients are
 summed at ``collect_grads`` time (the pipeline analogue of Megatron's
@@ -95,6 +102,15 @@ def build_encdec_grad_step(cfg: ArchConfig, impl: Optional[str] = None):
     return grad_step
 
 
+def _period_remat(k: int) -> bool:
+    """Whether a stage of ``k`` periods checkpoints each period inside its
+    program. With one period the stage input, stashed or rematerialized at
+    stage granularity, already is the checkpoint: an inner one would only
+    run the forward once more. With several it bounds a program's memory
+    to one period's activations."""
+    return k > 1
+
+
 def _stage_apply(cfg: ArchConfig, k: int, n_stages: int, impl, j: int,
                  sparams, x_or_batch, batch_aux):
     """Stage forward as a module-level pure function of static config —
@@ -109,7 +125,7 @@ def _stage_apply(cfg: ArchConfig, k: int, n_stages: int, impl, j: int,
     sub_cfg = dataclasses.replace(cfg, n_layers=k * len(cfg.layer_pattern))
     h, _, _ = T.stack_fwd(sparams["stack"], h, sub_cfg,
                           positions=positions, segment_ids=segment_ids,
-                          impl=impl, remat=True)
+                          impl=impl, remat=_period_remat(k))
     if j == n_stages - 1:
         h = L.rms_norm(h, sparams["final_norm"], cfg.norm_eps)
         head = sparams.get("head", sparams.get("embed"))
@@ -143,7 +159,8 @@ def _encdec_stage_apply(cfg: ArchConfig, k: int, n_stages: int,
             h = x_or_batch
         h = T.enc_stage_fwd(sparams["stack"], h, sub_cfg,
                             positions=batch_aux["enc_positions"],
-                            segment_ids=enc_seg, impl=impl, remat=True)
+                            segment_ids=enc_seg, impl=impl,
+                            remat=_period_remat(k))
         if j == n_enc_stages - 1:
             h = L.rms_norm(h, sparams["enc_norm"], cfg.norm_eps)
         return h
@@ -157,7 +174,8 @@ def _encdec_stage_apply(cfg: ArchConfig, k: int, n_stages: int,
                          hd, he, sub_cfg,
                          positions=batch_aux["dec_positions"],
                          segment_ids=batch_aux["dec_segment_ids"],
-                         enc_segment_ids=enc_seg, impl=impl, remat=True)
+                         enc_segment_ids=enc_seg, impl=impl,
+                         remat=_period_remat(k))
     if j == n_stages - 1:
         hd = L.rms_norm(hd, sparams["dec_norm"], cfg.norm_eps)
         return _xent_sum(sparams["embed"], hd, batch_aux["labels"],
@@ -238,9 +256,8 @@ class PipelinedModel:
         return out
 
     # ------------------------- stage compute ---------------------------
-    def _keys(self, j: int, shape: tuple):
-        return (("fwd", self._cache_ns, j) + shape,
-                ("bwd", self._cache_ns, j) + shape)
+    def _key(self, kind: str, j: int, shape: tuple) -> tuple:
+        return (kind, self._cache_ns, j) + shape
 
     def compile_plan(self, plan: ExecutionPlan, batches: dict) -> None:
         """Compile every stage program the plan needs and the cache lacks.
@@ -249,11 +266,13 @@ class PipelinedModel:
         against the executor's channel timeout (a full-width stage program
         takes seconds to compile). Programs are compiled ahead of time from
         shapes, and the cache holds the executables: the stage threads only
-        ever run compiled code. The backward programs take the stage's grad
-        accumulator as a donated argument and return it updated in place.
-        Programs are named ``stage{j}_fwd`` / ``stage{j}_bwd``, so a device
-        trace's ``XLA Modules`` line reads ``jit_stage{j}_fwd``; each
-        compile runs under a ``dynapipe.compile`` span.
+        ever run compiled code. Stages before the last get a forward and a
+        backward program; the last stage gets one forward-and-backward
+        program. Programs that produce parameter gradients take the stage's
+        grad accumulator as a donated argument and return it updated in
+        place. Programs are named ``stage{j}_{kind}``, so a device trace's
+        ``XLA Modules`` line reads ``jit_stage{j}_fwd``, ``_bwd`` or
+        ``_fwd_bwd``; each compile runs under a ``dynapipe.compile`` span.
         """
         c = self.n_stages
         firsts: dict[tuple, int] = {}
@@ -263,42 +282,48 @@ class PipelinedModel:
         # so a shared step cache that outlives this model does not pin the
         # retired instance (and its full_params) in memory
         apply_fn, static = self._apply_fn, self._apply_static
+        remat = _period_remat(self.k)
+
+        def ensure(kind, j, shape, fn, args, **jit_kw):
+            self.step_cache.get(self._key(kind, j, shape), lambda: _compile(
+                fn, j, kind, shape, remat, args, **jit_kw))
+
         for shape, mb in firsts.items():
-            if all(k in self.step_cache.keys()
-                   for j in range(c) for k in self._keys(j, shape)):
+            if all(self._key(kind, j, shape) in self.step_cache.keys()
+                   for j in range(c) for kind in (
+                       ("fwd_bwd",) if j == c - 1 else ("fwd", "bwd"))):
                 continue
             b = {k: _struct(jnp.asarray(v)) for k, v in batches[mb].items()}
             aux = {k: b[k] for k in self._aux_keys if k in b}
             x = b
             for j in range(c):
                 sp = jax.eval_shape(lambda j=j: self.stage_params(j))
-                fwd_key, bwd_key = self._keys(j, shape)
+                if j == c - 1:
+                    def fwd_bwd(sp_, x_, aux_, acc, j=j):
+                        def loss(p, x2):
+                            return apply_fn(*static, j, p, x2, aux_)
+                        (loss_sum, w_sum), (gp, gx) = jax.value_and_grad(
+                            loss, argnums=(0, 1), has_aux=True)(sp_, x_)
+                        return (loss_sum, w_sum,
+                                jax.tree.map(jnp.add, acc, gp), gx)
+                    ensure("fwd_bwd", j, shape, fwd_bwd, (sp, x, aux, sp),
+                           donate_argnums=3)
+                    continue
 
                 def fwd(sp_, x_, aux_, j=j):
                     return apply_fn(*static, j, sp_, x_, aux_)
-                self.step_cache.get(fwd_key, lambda: _compile(
-                    fwd, j, "fwd", shape, (sp, x, aux)))
-                if j == c - 1:
-                    def bwd(sp_, x_, aux_, acc, j=j):
-                        def scalar(p, x2):
-                            return apply_fn(*static, j, p, x2, aux_)[0]
-                        gp, gx = jax.grad(scalar, argnums=(0, 1))(sp_, x_)
-                        return jax.tree.map(jnp.add, acc, gp), gx
-                    args = (sp, x, aux, sp)
-                else:
-                    def bwd(sp_, x_, g_out, aux_, acc, j=j):
-                        _, vjp = jax.vjp(
-                            lambda p, x2: apply_fn(*static, j, p, x2, aux_),
-                            sp_, x_)
-                        gp, gx = vjp(g_out)
-                        return jax.tree.map(jnp.add, acc, gp), gx
-                    x_next = jax.eval_shape(fwd, sp, x, aux)
-                    args = (sp, x, x_next, aux, sp)
-                self.step_cache.get(bwd_key, lambda: _compile(
-                    bwd, j, "bwd", shape, args,
-                    donate_argnums=len(args) - 1))
-                if j < c - 1:
-                    x = x_next
+
+                def bwd(sp_, x_, g_out, aux_, acc, j=j):
+                    _, vjp = jax.vjp(
+                        lambda p, x2: apply_fn(*static, j, p, x2, aux_),
+                        sp_, x_)
+                    gp, gx = vjp(g_out)
+                    return jax.tree.map(jnp.add, acc, gp), gx
+                x_next = jax.eval_shape(fwd, sp, x, aux)
+                ensure("fwd", j, shape, fwd, (sp, x, aux))
+                ensure("bwd", j, shape, bwd, (sp, x, x_next, aux, sp),
+                       donate_argnums=4)
+                x = x_next
 
     # ------------------------- callbacks -------------------------------
     def make_callbacks(self, plan: ExecutionPlan, batches: dict,
@@ -308,7 +333,9 @@ class PipelinedModel:
         Returns (callbacks, result) where result collects
         {"stage_grads", "loss_sum", "weight_sum"} after run(). Every stage
         program is compiled here (:meth:`compile_plan`), before the
-        callbacks exist.
+        callbacks exist. The last stage's forward runs its
+        forward-and-backward program and stashes the input gradient; its
+        backward hands that gradient on.
         """
         self.compile_plan(plan, batches)
         c = self.n_stages
@@ -327,8 +354,13 @@ class PipelinedModel:
             return {k: b[k] for k in aux_keys if k in b}
 
         def program(kind, j, mb):
-            key = self._keys(j, self._batch_shape(batches[mb]))[kind]
+            key = self._key(kind, j, self._batch_shape(batches[mb]))
             return self.step_cache.get(key, _not_compiled(key))
+
+        def acc_of(j):
+            acc = result["stage_grads"][j]
+            return jax.tree.map(jnp.zeros_like, sparams[j]) \
+                if acc is None else acc
 
         def make_forward(j):
             def forward(mb, h_in=None):
@@ -338,30 +370,26 @@ class PipelinedModel:
                              for k, v in batches[mb].items()}
                 else:
                     x = h_in
-                stashes[j][mb] = x
-                out = program(0, j, mb)(sparams[j], x, aux_of(mb))
-                if j == c - 1:
-                    stashes[j][mb] = (x, out)
-                    loss_sum, w_sum = out
-                    with spans.span(spans.LOSS_SYNC):
-                        result["loss_sum"] += float(loss_sum)
-                        result["weight_sum"] += float(w_sum)
-                    return None
-                return out
+                if j < c - 1:
+                    stashes[j][mb] = x
+                    return program("fwd", j, mb)(sparams[j], x, aux_of(mb))
+                loss_sum, w_sum, acc, gx = program("fwd_bwd", j, mb)(
+                    sparams[j], x, aux_of(mb), acc_of(j))
+                result["stage_grads"][j] = acc
+                stashes[j][mb] = gx
+                with spans.span(spans.LOSS_SYNC):
+                    result["loss_sum"] += float(loss_sum)
+                    result["weight_sum"] += float(w_sum)
+                return None
             return forward
 
         def make_backward(j):
             def backward(mb, g_out):
-                acc = result["stage_grads"][j]
-                if acc is None:
-                    acc = jax.tree.map(jnp.zeros_like, sparams[j])
-                bwd = program(1, j, mb)
                 if j == c - 1:
-                    x, _ = stashes[j].pop(mb)
-                    acc, gx = bwd(sparams[j], x, aux_of(mb), acc)
-                else:
-                    x = stashes[j].pop(mb)
-                    acc, gx = bwd(sparams[j], x, g_out, aux_of(mb), acc)
+                    return stashes[j].pop(mb)
+                acc, gx = program("bwd", j, mb)(
+                    sparams[j], stashes[j].pop(mb), g_out, aux_of(mb),
+                    acc_of(j))
                 result["stage_grads"][j] = acc
                 if j == 0:
                     return None
@@ -383,13 +411,15 @@ def _struct(x):
     return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
 
-def _compile(fn, j: int, kind: str, shape: tuple, args, **jit_kw):
+def _compile(fn, j: int, kind: str, shape: tuple, remat: bool, args,
+             **jit_kw):
     """Ahead-of-time compile of stage ``j``'s ``kind`` program, named
     ``stage{j}_{kind}`` (the name is all that differs from a plain
-    ``jax.jit(fn)``: the compiled code is the same)."""
+    ``jax.jit(fn)``: the compiled code is the same). ``remat`` says whether
+    the program checkpoints each period; the span records it."""
     fn.__name__ = fn.__qualname__ = f"stage{j}_{kind}"
     with spans.span(spans.COMPILE, stage=j, kind=kind,
-                    shape="x".join(map(str, shape))):
+                    shape="x".join(map(str, shape)), remat=int(remat)):
         return jax.jit(fn, **jit_kw).lower(*args).compile()
 
 

@@ -14,8 +14,9 @@ def test_chip_smoke_training_passes_its_checks_at_toy_size():
                               impl="interpret")
     assert chip_smoke.check_training(report, "interpret") == []
     assert len(report["losses"]) == 2
-    # a forward and a backward program per stage for every shape it ran
-    assert len(report["stage_programs"]) >= 2 * chip_smoke.N_STAGES
+    # for every shape it ran: a forward and a backward program for each
+    # stage before the last, one forward-and-backward program for the last
+    assert len(report["stage_programs"]) >= 2 * chip_smoke.N_STAGES - 1
     assert report["compiles"] > 0
 
 

@@ -111,7 +111,7 @@ def test_main_thread_spans_nest_in_order(traced):
     assert compiles and all(
         s[0] == main and any(_inside(s, u) for u in setups) for s in compiles)
     assert {(s[4]["stage"], s[4]["kind"]) for s in compiles} == {
-        (j, k) for j in (0, 1) for k in ("fwd", "bwd")}
+        (0, "fwd"), (0, "bwd"), (1, "fwd_bwd")}
     assert all(s[4]["shape"].count("x") == 1 for s in compiles)
 
 
@@ -156,7 +156,10 @@ def test_plan_spans_on_planner_threads(traced):
 
 def test_device_programs_carry_stable_names(traced):
     _, _, params, cache = traced
-    for kind in ("fwd", "bwd"):
+    assert {(key[2], kind) for kind in ("fwd", "bwd", "fwd_bwd")
+            for key in cache.keys_for(kind)} == {
+        (0, "fwd"), (0, "bwd"), (1, "fwd_bwd")}
+    for kind in ("fwd", "bwd", "fwd_bwd"):
         for key, exe in zip(cache.keys_for(kind), cache.entries(kind)):
             assert exe.as_text().startswith(
                 f"HloModule jit_stage{key[2]}_{kind},")
