@@ -80,6 +80,10 @@ class ArchConfig:
     final_softcap: Optional[float] = None   # gemma2 final-logit soft-capping
     window: int = 0                          # sliding window for "attn_local"
     causal: bool = True                      # False => encoder-only (hubert)
+    # T5's relative position bias: buckets per table (0 = none) and the
+    # distance past which all share one bucket (see ``t5_block``)
+    rel_attn_buckets: int = 0
+    rel_attn_max_distance: int = 128
 
     # MoE
     n_experts: int = 0
@@ -149,6 +153,15 @@ class ArchConfig:
         assert self.n_layers % period == 0, (self.name, self.n_layers, period)
         reps = self.n_layers // period
         return tuple(self.layer_pattern) * reps
+
+    @property
+    def t5_block(self) -> bool:
+        """T5's own block (encoder-decoder): a (buckets, heads) relative
+        bias table per stack shared by its layers, unscaled scores with
+        W_q initialised at (d_model·d_head)^-1/2, cross attention between a
+        decoder layer's self attention and its MLP, and the decoder output
+        scaled by d_model^-1/2 before the tied head."""
+        return self.rel_attn_buckets > 0
 
     @property
     def n_periods(self) -> int:
@@ -253,6 +266,7 @@ ARCH_IDS = [
     # the paper's own models (benchmark analogues, not assignment cells)
     "gpt-paper",
     "t5-paper",
+    "t5-11b",
 ]
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

@@ -138,7 +138,8 @@ def plan_replica(
 def plan_iteration(lengths, cost: CostModel, pcfg: PlannerConfig,
                    recompute: RecomputePolicy = RecomputePolicy.FULL) -> IterationPlan:
     """One iteration's plan, under a ``dynapipe.plan`` span whose args are
-    its micro-batch count, real and padded tokens and predicted time."""
+    its micro-batch count, real and padded tokens and predicted time (and
+    per side, for an encoder-decoder plan)."""
     with spans.span(spans.PLAN) as sp:
         it_plan = _plan_iteration(lengths, cost, pcfg, recompute)
         sp.set_metadata(
@@ -146,7 +147,8 @@ def plan_iteration(lengths, cost: CostModel, pcfg: PlannerConfig,
             real_tokens=int(np.sum(lengths)),
             padded_tokens=int(sum(m.padded_tokens
                                   for m in it_plan.micro_batches)),
-            predicted_ms=it_plan.predicted_iteration_time * 1e3)
+            predicted_ms=it_plan.predicted_iteration_time * 1e3,
+            **spans.encdec_tokens(lengths, it_plan.micro_batches))
     return it_plan
 
 

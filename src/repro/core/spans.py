@@ -16,7 +16,15 @@ Main (runner) thread, inside one ``dynapipe.iteration`` per loop pass:
 its own ``device_put`` and ``loss_sync``). Stage compute threads:
 ``stage{j}.fwd`` / ``stage{j}.bwd`` (with ``device_put`` in stage 0's
 forward and ``loss_sync`` in the last stage's) and ``recv_wait``. Planner
-threads: ``plan``.
+threads: ``plan``. An encoder-decoder plan's ``plan`` and ``iteration``
+also carry ``real_enc_tokens``, ``padded_enc_tokens``, ``real_dec_tokens``
+and ``padded_dec_tokens`` (``encdec_tokens``).
+
+Device programs: the stage programs ``jit_stage{j}_fwd`` / ``_bwd`` and
+the last stage's ``jit_stage{c-1}_fwd_bwd``, AdamW's ``jit_adamw_step``.
+Within them, the Pallas attention kernels with T5's relative position bias
+are named ``flash_fwd_relbias``, ``flash_dq_relbias`` and
+``flash_dkv_relbias``; the others keep their kernels' function names.
 """
 from __future__ import annotations
 
@@ -36,6 +44,22 @@ RECV_WAIT = "dynapipe.recv_wait"
 PLAN = "dynapipe.plan"
 
 _annotation = None
+
+
+def encdec_tokens(lengths, micro_batches) -> dict:
+    """Real and padded tokens per side of an encoder-decoder plan, as span
+    args; {} when the micro-batches are decoder-only (an int ``seq``).
+    ``lengths`` is (n, 2): encoder, decoder."""
+    sides = [m for m in micro_batches if isinstance(m.seq, (tuple, list))]
+    if not sides:
+        return {}
+    enc = dec = 0
+    for n_enc, n_dec in lengths:
+        enc, dec = enc + int(n_enc), dec + int(n_dec)
+    return {"real_enc_tokens": enc,
+            "padded_enc_tokens": sum(m.mbs * int(m.seq[0]) for m in sides),
+            "real_dec_tokens": dec,
+            "padded_dec_tokens": sum(m.mbs * int(m.seq[1]) for m in sides)}
 
 
 def stage(j: int, kind: str) -> str:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ragged_attention as _ra
+from repro.kernels import relpos
 from repro.kernels import ssd as _ssd
 from repro.kernels import ref as _ref
 
@@ -53,6 +54,7 @@ def attention(
     causal=True, window=0, softcap=None,
     q_positions=None, kv_positions=None,
     q_segment_ids=None, kv_segment_ids=None,
+    sm_scale=None, rel_bias=None, rel_max_distance=128,
     block_q=512, block_kv=512, impl: str | None = None,
     chunk_strategy: str = "q",
 ):
@@ -64,6 +66,13 @@ def attention(
     chunk_strategy (ref path, long sequences): "q" scans query blocks
     (head-parallel attention), "head" scans head blocks (sequence-parallel
     attention, where the q seq dim is mesh-sharded and must not be scanned).
+
+    sm_scale multiplies q·k (default 1/sqrt(head dim); T5 uses 1).
+    rel_bias, an (H, n_buckets) table, adds T5's relative position bias
+    ``table[h, bucket(kv_pos − q_pos)]``, bucketed causally when ``causal``
+    (see ``relpos``); the kernels read the relative position off the row
+    index, so positions must count up by one within each segment. The ref
+    path does not chunk an unscaled or biased call.
     """
     impl = _resolve(impl)
     h = q.shape[2]
@@ -77,6 +86,21 @@ def attention(
         else:
             kv_segment_ids = jnp.zeros(k.shape[:2], jnp.int32)
     ragged = q_segment_ids is not None
+    if impl == "ref" and (sm_scale is not None or rel_bias is not None):
+        if q_positions is None:
+            q_positions = jnp.broadcast_to(
+                jnp.arange(q.shape[1], dtype=jnp.int32)[None], q.shape[:2])
+        if kv_positions is None:
+            kv_positions = jnp.broadcast_to(
+                jnp.arange(k.shape[1], dtype=jnp.int32)[None], k.shape[:2])
+        bias = None if rel_bias is None else relpos.bias(
+            rel_bias, q_positions, kv_positions,
+            max_distance=rel_max_distance, bidirectional=not causal)
+        return _ref.attention_ref(
+            q, k, v, causal=causal, window=window, softcap=softcap,
+            q_positions=q_positions, kv_positions=kv_positions,
+            q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
+            sm_scale=sm_scale, bias=bias)
     if impl == "ref":
         # score-matrix element count decides chunking; batch rows multiply
         # the working set exactly like heads do, so B is part of the bound
@@ -104,11 +128,15 @@ def attention(
             q, k, v, q_segment_ids, kv_segment_ids, causal=causal,
             window=window, softcap=softcap,
             q_positions=q_positions, kv_positions=kv_positions,
+            sm_scale=sm_scale, rel_bias=rel_bias,
+            rel_max_distance=rel_max_distance,
             block_q=block_q, block_kv=block_kv, interpret=interpret,
         )
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window, softcap=softcap,
         q_positions=q_positions, kv_positions=kv_positions,
+        sm_scale=sm_scale, rel_bias=rel_bias,
+        rel_max_distance=rel_max_distance,
         block_q=block_q, block_kv=block_kv, interpret=interpret,
     )
 

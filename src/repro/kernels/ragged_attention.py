@@ -18,6 +18,7 @@ indexing are all shared with ``flash_attention.py`` — this module binds
 the segmented variant of the same kernel bodies, so the backward carries
 the identical segment-range block-skip predicate (cross-sample blocks are
 skipped in *both* passes, where they cost twice what they do in forward).
+T5's relative position bias rides along the same way (``rel_bias``).
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import (
     NEG_INF,            # noqa: F401  (re-exported for callers/tests)
     _int_ct,
+    _scale,
     live_block_mask,    # noqa: F401  (segment-aware liveness, re-exported)
     mha_backward,
     mha_forward,
@@ -36,32 +38,38 @@ from repro.kernels.flash_attention import (
 )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
-def _ragged(q, k, v, qseg, kseg, qpos, kpos, causal, window, softcap,
-            block_q, block_kv, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(8, 16)))
+def _ragged(q, k, v, qseg, kseg, qpos, kpos, table, causal, window, softcap,
+            sm_scale, max_distance, block_q, block_kv, interpret):
     o, _ = mha_forward(q, k, v, qpos, kpos, qseg, kseg, causal=causal,
-                       window=window, softcap=softcap, block_q=block_q,
-                       block_kv=block_kv, interpret=interpret)
+                       window=window, softcap=softcap, sm_scale=sm_scale,
+                       rel_table=table, rel_max_distance=max_distance,
+                       block_q=block_q, block_kv=block_kv,
+                       interpret=interpret)
     return o
 
 
-def _ragged_fwd(q, k, v, qseg, kseg, qpos, kpos, causal, window, softcap,
-                block_q, block_kv, interpret):
+def _ragged_fwd(q, k, v, qseg, kseg, qpos, kpos, table, causal, window,
+                softcap, sm_scale, max_distance, block_q, block_kv,
+                interpret):
     o, lse = mha_forward(q, k, v, qpos, kpos, qseg, kseg, causal=causal,
-                         window=window, softcap=softcap, block_q=block_q,
-                         block_kv=block_kv, interpret=interpret)
-    return o, (q, k, v, qseg, kseg, qpos, kpos, o, lse)
+                         window=window, softcap=softcap, sm_scale=sm_scale,
+                         rel_table=table, rel_max_distance=max_distance,
+                         block_q=block_q, block_kv=block_kv,
+                         interpret=interpret)
+    return o, (q, k, v, qseg, kseg, qpos, kpos, table, o, lse)
 
 
-def _ragged_bwd(causal, window, softcap, block_q, block_kv, interpret,
-                res, do):
-    q, k, v, qseg, kseg, qpos, kpos, o, lse = res
-    dq, dk, dv = mha_backward(
+def _ragged_bwd(causal, window, softcap, sm_scale, max_distance, block_q,
+                block_kv, interpret, res, do):
+    q, k, v, qseg, kseg, qpos, kpos, table, o, lse = res
+    dq, dk, dv, dtable = mha_backward(
         q, k, v, qpos, kpos, qseg, kseg, o, lse, do,
-        causal=causal, window=window, softcap=softcap,
+        causal=causal, window=window, softcap=softcap, sm_scale=sm_scale,
+        rel_table=table, rel_max_distance=max_distance,
         block_q=block_q, block_kv=block_kv, interpret=interpret)
     return (dq, dk, dv, _int_ct(qseg), _int_ct(kseg),
-            _int_ct(qpos), _int_ct(kpos))
+            _int_ct(qpos), _int_ct(kpos), dtable)
 
 
 _ragged.defvjp(_ragged_fwd, _ragged_bwd)
@@ -79,6 +87,9 @@ def ragged_attention(
     softcap: float | None = None,
     q_positions: jax.Array | None = None,
     kv_positions: jax.Array | None = None,
+    sm_scale: float | None = None,          # default 1/sqrt(D)
+    rel_bias: jax.Array | None = None,      # (H, n_buckets) table
+    rel_max_distance: int = 128,
     block_q: int = 512,
     block_kv: int = 512,
     interpret: bool = False,
@@ -93,8 +104,11 @@ def ragged_attention(
         q_positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     if kv_positions is None:
         kv_positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    if rel_bias is not None:
+        rel_bias = rel_bias.astype(jnp.float32)
     return _ragged(q, k, v, q_segment_ids.astype(jnp.int32),
                    kv_segment_ids.astype(jnp.int32),
                    q_positions.astype(jnp.int32),
-                   kv_positions.astype(jnp.int32), causal, int(window),
-                   softcap, block_q, block_kv, interpret)
+                   kv_positions.astype(jnp.int32), rel_bias, causal,
+                   int(window), softcap, _scale(sm_scale, d),
+                   int(rel_max_distance), block_q, block_kv, interpret)

@@ -34,6 +34,8 @@ def attention_ref(
     kv_positions: jax.Array | None = None,  # (B, S)
     q_segment_ids: jax.Array | None = None,   # (B, T); -1 = padding
     kv_segment_ids: jax.Array | None = None,  # (B, S); -1 = padding
+    sm_scale: float | None = None,            # default 1/sqrt(D)
+    bias: jax.Array | None = None,            # (B, H, T, S) added to scores
 ) -> jax.Array:
     """Materialized-scores attention. Returns (B, T, H, D) in q.dtype."""
     b, t, h, d = q.shape
@@ -48,7 +50,12 @@ def attention_ref(
         kv_positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
     scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32), k.astype(jnp.float32))
-    scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if sm_scale is None:
+        scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    else:
+        scores = scores * sm_scale
+    if bias is not None:
+        scores = scores + bias
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
 

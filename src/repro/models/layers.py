@@ -91,8 +91,10 @@ def init_attention(key, cfg: ArchConfig):
     dt = _dtype(cfg)
     ks = jax.random.split(key, 4)
     # fused-head 2D layouts: h*dh and kv*dh divide any power-of-two axis
+    # T5 folds the 1/sqrt(d_head) score scale into W_q's init
+    wq_scale = (d * dh) ** -0.5 if cfg.t5_block else d ** -0.5
     p = {
-        "wq": _init(ks[0], (d, h * dh), d ** -0.5, dt),
+        "wq": _init(ks[0], (d, h * dh), wq_scale, dt),
         "wk": _init(ks[1], (d, kv * dh), d ** -0.5, dt),
         "wv": _init(ks[2], (d, kv * dh), d ** -0.5, dt),
         "wo": _init(ks[3], (h * dh, d), (h * dh) ** -0.5, dt),
@@ -102,6 +104,12 @@ def init_attention(key, cfg: ArchConfig):
         p["bk"] = jnp.zeros((kv * dh,), dt)
         p["bv"] = jnp.zeros((kv * dh,), dt)
     return p
+
+
+def init_rel_bias(key, cfg: ArchConfig):
+    """A stack's T5 relative bias table, (buckets, heads), N(0, d^-1/2)."""
+    return _init(key, (cfg.rel_attn_buckets, cfg.n_heads), cfg.d_model ** -0.5,
+                 _dtype(cfg))
 
 
 def attention_logical(cfg: ArchConfig):
@@ -159,6 +167,7 @@ def attention_fwd(
     cache_pos: Optional[jax.Array] = None,  # scalar int32: tokens already cached
     mode: str = "train",                # train | prefill | decode
     impl: Optional[str] = None,
+    rel_bias: Optional[jax.Array] = None,   # (buckets, H): T5's stack table
 ):
     window = cfg.window if local else 0
     b, t, d = x.shape
@@ -200,9 +209,12 @@ def attention_fwd(
             q, k, v, causal=cfg.causal, window=window, softcap=cfg.attn_softcap,
             q_positions=positions, kv_positions=positions,
             q_segment_ids=segment_ids, kv_segment_ids=segment_ids, impl=impl,
-            chunk_strategy=chunk,
+            chunk_strategy=chunk, sm_scale=1.0 if cfg.t5_block else None,
+            rel_bias=None if rel_bias is None else rel_bias.T,
+            rel_max_distance=cfg.rel_attn_max_distance,
         )
     else:
+        assert not cfg.t5_block, "T5's block trains only"
         s = cache["k"].shape[1]
         start = jnp.zeros((), jnp.int32) if mode == "prefill" else cache_pos
         ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),

@@ -51,7 +51,7 @@ def block_logical(cfg: ArchConfig, spec: LayerSpec):
 
 def block_fwd(p, h, cfg: ArchConfig, spec: LayerSpec, *,
               positions, segment_ids, cache=None, cache_pos=None,
-              mode="train", impl=None):
+              mode="train", impl=None, rel_bias=None):
     x = L.rms_norm(h, p["ln1"], cfg.norm_eps)
     if spec.mixer == "mamba":
         y, new_cache = M.mamba_fwd(p["mixer"], x, cfg, cache=cache, mode=mode, impl=impl)
@@ -60,6 +60,7 @@ def block_fwd(p, h, cfg: ArchConfig, spec: LayerSpec, *,
             p["mixer"], x, cfg, local=(spec.mixer == "attn_local"),
             positions=positions, segment_ids=segment_ids,
             cache=cache, cache_pos=cache_pos, mode=mode, impl=impl,
+            rel_bias=rel_bias,
         )
     h = h + y
     aux = jnp.zeros((), jnp.float32)
@@ -172,8 +173,9 @@ def _pin_fsdp(pparams, cfg: ArchConfig):
 
 def stack_fwd(params, h, cfg: ArchConfig, *,
               positions, segment_ids, cache=None, cache_pos=None,
-              mode="train", impl=None, remat=True):
-    """Scan over periods. Returns (h, new_cache, aux_sum)."""
+              mode="train", impl=None, remat=True, rel_bias=None):
+    """Scan over periods. Returns (h, new_cache, aux_sum). ``rel_bias`` is
+    the stack's T5 bias table, shared by every layer."""
 
     def period_fn(h, xs):
         pparams, pcache = xs
@@ -186,6 +188,7 @@ def stack_fwd(params, h, cfg: ArchConfig, *,
                 pparams[f"l{i}"], h, cfg, spec,
                 positions=positions, segment_ids=segment_ids,
                 cache=lc, cache_pos=cache_pos, mode=mode, impl=impl,
+                rel_bias=rel_bias,
             )
             new_caches.append(nc if nc is not None else jnp.zeros((), jnp.float32))
             aux_total = aux_total + aux
@@ -235,7 +238,9 @@ def init_encdec(key, cfg: ArchConfig):
     ``n_periods``, like the enc/dec stacks) so they slice into pipeline
     stages the same way: stage j of the decoder owns ``cross[j*k:(j+1)*k]``
     alongside ``dec[j*k:(j+1)*k]``. One cross block runs after each period
-    (T5 has per-layer cross-attn; t5-paper's period is 1 layer, so exact)."""
+    (T5 has per-layer cross-attn; t5-paper's period is 1 layer, so exact).
+    T5's block adds one relative bias table per stack, ``enc_rel_bias``
+    and ``dec_rel_bias`` (buckets, heads), from keys 3 and 5."""
     ks = jax.random.split(key, 6)
     dt = L._dtype(cfg)
     dec_cross = []
@@ -243,7 +248,7 @@ def init_encdec(key, cfg: ArchConfig):
         kk = jax.random.fold_in(ks[4], i)
         dec_cross.append({"ln": jnp.zeros((cfg.d_model,), dt),
                           "attn": L.init_attention(kk, cfg)})
-    return {
+    params = {
         "embed": L._init(ks[0], (cfg.vocab_padded, cfg.d_model), 1.0, dt),
         "enc": init_stack(ks[1], cfg),
         "dec": init_stack(ks[2], cfg),
@@ -251,6 +256,10 @@ def init_encdec(key, cfg: ArchConfig):
         "enc_norm": jnp.zeros((cfg.d_model,), dt),
         "dec_norm": jnp.zeros((cfg.d_model,), dt),
     }
+    if cfg.t5_block:
+        params["enc_rel_bias"] = L.init_rel_bias(ks[3], cfg)
+        params["dec_rel_bias"] = L.init_rel_bias(ks[5], cfg)
+    return params
 
 
 def cross_attention_fwd(p, x, he, cfg: ArchConfig, *,
@@ -269,33 +278,61 @@ def cross_attention_fwd(p, x, he, cfg: ArchConfig, *,
     from repro.kernels import ops
     o = ops.attention(q, k, v, causal=False,
                       q_segment_ids=q_segment_ids,
-                      kv_segment_ids=kv_segment_ids, impl=impl)
+                      kv_segment_ids=kv_segment_ids, impl=impl,
+                      sm_scale=1.0 if cfg.t5_block else None)
     return jnp.einsum("bthk,hkd->btd", o,
                       p["attn"]["wo"].reshape(hh, dh, cfg.d_model))
 
 
 def enc_stage_fwd(stack_params, h, cfg: ArchConfig, *,
-                  positions, segment_ids=None, impl=None, remat=True):
+                  positions, segment_ids=None, impl=None, remat=True,
+                  rel_bias=None):
     """Encoder slice: non-causal stack over ``stack_params``'s periods.
     ``cfg.n_periods`` must equal the slice's period count (pipeline stages
-    pass a ``dataclasses.replace``d sub-config). ``h`` is already embedded."""
+    pass a ``dataclasses.replace``d sub-config). ``h`` is already embedded.
+    ``rel_bias`` is T5's encoder table."""
     enc_cfg = cfg if not cfg.causal else _replace_causal(cfg, False)
     h, _, _ = stack_fwd(stack_params, h, enc_cfg, positions=positions,
-                        segment_ids=segment_ids, impl=impl, remat=remat)
+                        segment_ids=segment_ids, impl=impl, remat=remat,
+                        rel_bias=rel_bias)
     return h
+
+
+def t5_dec_layer(p, cross_p, h, he, cfg: ArchConfig, *, positions,
+                 segment_ids, enc_segment_ids, rel_bias, impl=None):
+    """T5's decoder layer: causal self-attention with the decoder table,
+    then cross-attention against ``he``, then the MLP, each pre-norm."""
+    y, _ = L.attention_fwd(p["mixer"], L.rms_norm(h, p["ln1"], cfg.norm_eps),
+                           cfg, local=False, positions=positions,
+                           segment_ids=segment_ids, impl=impl,
+                           rel_bias=rel_bias)
+    h = h + y
+    h = h + cross_attention_fwd(cross_p, h, he, cfg,
+                                q_segment_ids=segment_ids,
+                                kv_segment_ids=enc_segment_ids, impl=impl)
+    h = h + L.mlp_fwd(p["ffn"], L.rms_norm(h, p["ln2"], cfg.norm_eps), cfg)
+    return shard(h, "dp", "sp", None)
 
 
 def dec_stage_fwd(params, hd, he, cfg: ArchConfig, *,
                   positions, segment_ids=None, enc_segment_ids=None,
-                  impl=None, remat=True):
+                  impl=None, remat=True, rel_bias=None):
     """Decoder slice: causal self-attention periods, each followed by
-    cross-attention against the encoder output ``he``. ``params`` carries
-    period-major ``{"stack", "cross"}`` slices of equal leading length;
-    ``he`` is the *final* encoder output, which the pipeline forwards
-    unchanged to every decoder stage."""
+    cross-attention against the encoder output ``he`` (T5's block:
+    ``t5_dec_layer``, cross-attention before the MLP, with the decoder
+    table ``rel_bias``). ``params`` carries period-major ``{"stack",
+    "cross"}`` slices of equal leading length; ``he`` is the *final*
+    encoder output, which the pipeline forwards unchanged to every decoder
+    stage."""
 
     def dec_period(h, xs):
         pparams, cross_p = xs
+        if cfg.t5_block:
+            assert len(cfg.layer_pattern) == 1, "T5's period is one layer"
+            return t5_dec_layer(pparams["l0"], cross_p, h, he, cfg,
+                                positions=positions, segment_ids=segment_ids,
+                                enc_segment_ids=enc_segment_ids,
+                                rel_bias=rel_bias, impl=impl), None
         for i, spec in enumerate(cfg.layer_pattern):
             h, _, _ = block_fwd(pparams[f"l{i}"], h, cfg, spec,
                                 positions=positions, segment_ids=segment_ids,
@@ -317,7 +354,7 @@ def encdec_fwd(params, enc_tokens, dec_tokens, cfg: ArchConfig, *,
     """Sequential oracle: the full encoder-decoder forward, composed of the
     same ``enc_stage_fwd``/``dec_stage_fwd`` primitives the pipelined
     execution slices — pipelined runs are parity-tested against this.
-    Returns decoder hidden states (B, T_dec, D)."""
+    Returns the tied head's input (B, T_dec, D): ``dec_head_input``."""
     b, t_enc = enc_tokens.shape
     t_dec = dec_tokens.shape[1]
     if enc_positions is None:
@@ -329,15 +366,24 @@ def encdec_fwd(params, enc_tokens, dec_tokens, cfg: ArchConfig, *,
 
     he = jnp.take(params["embed"], enc_tokens, axis=0)
     he = enc_stage_fwd(params["enc"], he, cfg, positions=enc_positions,
-                       segment_ids=enc_segments, impl=impl, remat=remat)
+                       segment_ids=enc_segments, impl=impl, remat=remat,
+                       rel_bias=params.get("enc_rel_bias"))
     he = L.rms_norm(he, params["enc_norm"], cfg.norm_eps)
 
     hd = jnp.take(params["embed"], dec_tokens, axis=0)
     hd = dec_stage_fwd({"stack": params["dec"], "cross": params["cross"]},
                        hd, he, cfg, positions=dec_positions,
                        segment_ids=dec_segments,
-                       enc_segment_ids=enc_segments, impl=impl, remat=remat)
-    return L.rms_norm(hd, params["dec_norm"], cfg.norm_eps)
+                       enc_segment_ids=enc_segments, impl=impl, remat=remat,
+                       rel_bias=params.get("dec_rel_bias"))
+    return dec_head_input(hd, params["dec_norm"], cfg)
+
+
+def dec_head_input(hd, dec_norm, cfg: ArchConfig):
+    """The decoder's final RMSNorm, and T5's d_model^-1/2 before the tied
+    head."""
+    hd = L.rms_norm(hd, dec_norm, cfg.norm_eps)
+    return hd * cfg.d_model ** -0.5 if cfg.t5_block else hd
 
 
 def _replace_causal(cfg: ArchConfig, causal: bool) -> ArchConfig:

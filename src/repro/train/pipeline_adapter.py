@@ -160,7 +160,8 @@ def _encdec_stage_apply(cfg: ArchConfig, k: int, n_stages: int,
         h = T.enc_stage_fwd(sparams["stack"], h, sub_cfg,
                             positions=batch_aux["enc_positions"],
                             segment_ids=enc_seg, impl=impl,
-                            remat=_period_remat(k))
+                            remat=_period_remat(k),
+                            rel_bias=sparams.get("rel_bias"))
         if j == n_enc_stages - 1:
             h = L.rms_norm(h, sparams["enc_norm"], cfg.norm_eps)
         return h
@@ -175,9 +176,10 @@ def _encdec_stage_apply(cfg: ArchConfig, k: int, n_stages: int,
                          positions=batch_aux["dec_positions"],
                          segment_ids=batch_aux["dec_segment_ids"],
                          enc_segment_ids=enc_seg, impl=impl,
-                         remat=_period_remat(k))
+                         remat=_period_remat(k),
+                         rel_bias=sparams.get("rel_bias"))
     if j == n_stages - 1:
-        hd = L.rms_norm(hd, sparams["dec_norm"], cfg.norm_eps)
+        hd = T.dec_head_input(hd, sparams["dec_norm"], cfg)
         return _xent_sum(sparams["embed"], hd, batch_aux["labels"],
                          batch_aux["loss_weights"], cfg)
     return (he, hd)
@@ -440,7 +442,9 @@ class EncDecPipelinedModel(PipelinedModel):
     its period-major cross-attention block) stages ``E..c-1``. Stage 0 owns
     the embedding table; the first decoder stage owns a copy (decoder-side
     lookup) and the last stage a third (tied LM head) — their gradients sum
-    in ``merge_stage_grads``. The final encoder output ``he`` is forwarded
+    in ``merge_stage_grads``. So do T5's relative bias tables: every
+    encoder stage holds ``enc_rel_bias`` and every decoder stage
+    ``dec_rel_bias``, as the stage's ``rel_bias``. The final encoder output ``he`` is forwarded
     along the pipe to every decoder stage as part of the ``(he, hd)``
     payload; ``jax.vjp`` over the pair carries cross-attention gradients
     back to the encoder stages through the ordinary grad channels.
@@ -496,6 +500,9 @@ class EncDecPipelinedModel(PipelinedModel):
                                       self.full_params["cross"])
             if j == self.n_stages - 1:
                 p["dec_norm"] = self.full_params["dec_norm"]
+        table = self._table(j)
+        if table in self.full_params:
+            p["rel_bias"] = self.full_params[table]
         if j == 0 or j == e or j == self.n_stages - 1:
             p["embed"] = self.full_params["embed"]
         return p
@@ -512,11 +519,17 @@ class EncDecPipelinedModel(PipelinedModel):
             cross=jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
                                *[g["cross"] for g in stage_grads[e:]]),
         )
-        for g in stage_grads:
+        for j, g in enumerate(stage_grads):
             for key in ("embed", "enc_norm", "dec_norm"):
                 if key in g:
                     out[key] = out[key] + g[key]
+            if "rel_bias" in g:
+                out[self._table(j)] = out[self._table(j)] + g["rel_bias"]
         return out
+
+    def _table(self, j: int) -> str:
+        """The full-params key of stage ``j``'s T5 relative bias table."""
+        return "enc_rel_bias" if j < self.n_enc_stages else "dec_rel_bias"
 
 
 def _xent_sum(head_w, h, labels, weights, cfg: ArchConfig):

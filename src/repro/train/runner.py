@@ -598,7 +598,8 @@ class PlanAheadRunner:
                             padded_tokens=int(padded), n_micro=n_micro,
                             plan_wait_ms=wait_s * 1e3,
                             predicted_compute_ms=1e3 * sum(
-                                m.t_fwd + m.t_bwd for m in micro))
+                                m.t_fwd + m.t_bwd for m in micro),
+                            **spans.encdec_tokens(gb.lengths, micro))
                         grads, loss_sum, w_sum, replica_s = \
                             self._execute_replicas(it, plan, it_plan, gb,
                                                    params)
