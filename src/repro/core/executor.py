@@ -152,11 +152,13 @@ class Channel:
 class StageCallbacks:
     """The JAX side of one stage.
 
-    forward(mb_id) -> None           stage 0 pulls its own micro-batch input
+    forward(mb_id)                   stage 0 pulls its own micro-batch input
     forward(mb_id, h_in)             other stages consume the received tensor
-      both return h_out (sent downstream) or None on the last stage
-    backward(mb_id, g_out | None) -> g_in | None
-      last stage passes g_out=None (it owns the loss)
+      both return h_out, sent downstream; the last stage's return value is
+      not sent
+    backward(mb_id, g_out | None) -> g_in
+      last stage passes g_out=None (it owns the loss); stage 0's return
+      value is not sent
     step() -> None                   REDUCE_AND_STEP
     """
     forward: Callable

@@ -11,14 +11,20 @@ Main (runner) thread, inside one ``dynapipe.iteration`` per loop pass:
 ``compile`` per stage program compiled there, with args ``stage``,
 ``kind`` (``fwd``, ``bwd`` or the last stage's ``fwd_bwd``), ``shape`` and
 ``remat``, 1 where the program checkpoints each period), ``pipeline``,
-``grad_merge``, ``optimizer`` (dispatch) and ``step_sync``; ``pipeline`` is
-``PipelineExecutor.run``, or the sequential path's micro-batch loop (with
-its own ``device_put`` and ``loss_sync``). Stage compute threads:
-``stage{j}.fwd`` / ``stage{j}.bwd`` (with ``device_put`` in stage 0's
-forward and ``loss_sync`` in the last stage's) and ``recv_wait``. Planner
-threads: ``plan``. An encoder-decoder plan's ``plan`` and ``iteration``
-also carry ``real_enc_tokens``, ``padded_enc_tokens``, ``real_dec_tokens``
-and ``padded_dec_tokens`` (``encdec_tokens``).
+``grad_merge`` (which first waits for the last stage's last program, so
+that the merge's output is not allocated beside it), ``optimizer``
+(dispatch), ``step_sync`` and ``loss_sync``, the step's one read of its
+losses, with arg ``n_reads`` (the device scalars read); ``iteration`` also
+carries ``pipeline_syncs``, the waits on the
+device made to time the pipeline (one per timed callback when the
+calibrator collects timings, one per replica when a monitor times
+replicas). ``pipeline`` is ``PipelineExecutor.run``, or the sequential
+path's micro-batch loop (with its own ``device_put`` and a ``loss_sync`` per
+micro-batch). Stage compute threads: ``stage{j}.fwd`` / ``stage{j}.bwd``
+(with ``device_put`` in stage 0's forward) and ``recv_wait``; none reads
+the device. Planner threads: ``plan``. An encoder-decoder plan's ``plan``
+and ``iteration`` also carry ``real_enc_tokens``, ``padded_enc_tokens``,
+``real_dec_tokens`` and ``padded_dec_tokens`` (``encdec_tokens``).
 
 Device programs: the stage programs ``jit_stage{j}_fwd`` / ``_bwd`` and
 the last stage's ``jit_stage{c-1}_fwd_bwd``, AdamW's ``jit_adamw_step``.

@@ -76,6 +76,10 @@ class BackendResult:
     ``timings`` entries are ``(kind, mb_id, seconds)`` with ``kind`` one of
     ``"f"``/``"b"`` (per-stage forward/backward, threads pipeline) or
     ``"total"`` (whole fwd+bwd for the micro-batch) — the calibrator input.
+    ``loss_sum`` is a float, or the threads pipeline's
+    :class:`~repro.train.pipeline_adapter.LossSum`, still on the device
+    until ``float()`` reads it. ``meta["pipeline_syncs"]`` counts the
+    pipeline's waits on the device (one per timed callback).
     """
     grads: Any
     loss_sum: float
@@ -129,18 +133,18 @@ def _scaled_adamw(opt_cfg, *, donate: bool):
 
 
 def _timed_callbacks(cbs: list[StageCallbacks], records: list, lock):
-    """Wrap every stage's fwd/bwd with wall timers (block_until_ready so
-    dispatch isn't mistaken for compute). Records ("f"/"b", mb_id, s)
-    under ``lock`` — callbacks run on stage threads. The last stage's
-    forward runs its forward and backward in one program, so it records
+    """Wrap every stage's fwd/bwd with wall timers that wait for what the
+    callback returns (block_until_ready, so dispatch isn't mistaken for
+    compute): one wait per record. Records ("f"/"b", mb_id, s) under
+    ``lock`` — callbacks run on stage threads. The last stage's forward
+    runs its forward and backward in one program, so it records
     ("total", mb_id, s) and its backward, which only hands the stashed
     gradient on, records nothing."""
     def timed(call, kind):
         def run(mb_id, *a):
             t0 = time.perf_counter()
             out = call(mb_id, *a)
-            if out is not None:
-                jax.block_until_ready(out)
+            jax.block_until_ready(out)
             with lock:
                 records.append((kind, mb_id, time.perf_counter() - t0))
             return out
@@ -248,9 +252,15 @@ class ThreadsBackend(ExecutionBackend):
             PipelineExecutor(plan, cbs, timeout=timeout, hook=hook).run()
             del cbs     # drops the stage param slices before the merge
             with spans.span(spans.GRAD_MERGE):
+                # the merge's output is allocated when it is dispatched:
+                # first let the last stage's last program finish and free
+                # its temporaries and param slice; stage 0's last backward
+                # is still queued, so the device does not drain
+                jax.block_until_ready(result["loss_sum"].parts[-1])
                 grads = pm.merge_stage_grads(result["stage_grads"])
             return BackendResult(grads, result["loss_sum"],
-                                 result["weight_sum"], records)
+                                 result["weight_sum"], records,
+                                 {"pipeline_syncs": len(records)})
 
         with spans.span(spans.PIPELINE):
             return self._execute_sequential(batches, params, hook,

@@ -833,6 +833,8 @@ class _Worker:
                 res.timings
         else:
             blob, loss_sum, w_sum, timings = b"", 0.0, 0.0, []
+        # the pipeline leaves its loss on the device (LossSum): read it
+        # here, where it leaves the process
         conn.send({"type": "result", "rank": self.rank,
                    "epoch": msg["epoch"], "iter": it,
                    "loss_sum": float(loss_sum),

@@ -11,8 +11,11 @@ memory model charges. The last stage trains each micro-batch in one
 ``value_and_grad`` program when its forward runs: the loss needs the
 primal output anyway, so a separate forward program would only run the
 stage twice. It stashes the input gradient, the size of its input, until
-the schedule's backward sends it. Within a stage program, periods are
-checkpointed only where a stage holds more than one (``_period_remat``).
+the schedule's backward sends it, and leaves the micro-batch's summed loss
+on the device (``LossSum``): no stage thread waits for the device, and the
+runner reads the step's losses once, when the step is done. Within a stage
+program, periods are checkpointed only where a stage holds more than one
+(``_period_remat``).
 
 Tied embeddings are duplicated on stages 0 and c-1; their gradients are
 summed at ``collect_grads`` time (the pipeline analogue of Megatron's
@@ -40,6 +43,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import spans
@@ -304,10 +308,9 @@ class PipelinedModel:
                     def fwd_bwd(sp_, x_, aux_, acc, j=j):
                         def loss(p, x2):
                             return apply_fn(*static, j, p, x2, aux_)
-                        (loss_sum, w_sum), (gp, gx) = jax.value_and_grad(
+                        (loss_sum, _), (gp, gx) = jax.value_and_grad(
                             loss, argnums=(0, 1), has_aux=True)(sp_, x_)
-                        return (loss_sum, w_sum,
-                                jax.tree.map(jnp.add, acc, gp), gx)
+                        return loss_sum, jax.tree.map(jnp.add, acc, gp), gx
                     ensure("fwd_bwd", j, shape, fwd_bwd, (sp, x, aux, sp),
                            donate_argnums=3)
                     continue
@@ -333,18 +336,22 @@ class PipelinedModel:
         """batches: mb_id -> batch dict (numpy/JAX arrays).
 
         Returns (callbacks, result) where result collects
-        {"stage_grads", "loss_sum", "weight_sum"} after run(). Every stage
-        program is compiled here (:meth:`compile_plan`), before the
-        callbacks exist. The last stage's forward runs its
-        forward-and-backward program and stashes the input gradient; its
-        backward hands that gradient on.
+        {"stage_grads", "loss_sum", "weight_sum"} after run(): the loss as a
+        :class:`LossSum` still on the device, the weight sum from the
+        host's batches (:func:`weight_sum`). Every stage program is
+        compiled here (:meth:`compile_plan`), before the callbacks exist.
+        The last stage's forward runs its forward-and-backward program,
+        stashes the input gradient and returns the loss it leaves on the
+        device; its backward hands that gradient on. Stage 0's backward
+        returns its gradient accumulator, which nothing sends: no callback
+        waits for the device, and a timer can wait on what each returns.
         """
         self.compile_plan(plan, batches)
         c = self.n_stages
         result = {
             "stage_grads": [None] * c,
-            "loss_sum": 0.0,
-            "weight_sum": 0.0,
+            "loss_sum": LossSum(),
+            "weight_sum": weight_sum(batches),
         }
         sparams = [self.stage_params(j) for j in range(c)]
         stashes: list[dict] = [dict() for _ in range(c)]
@@ -375,14 +382,12 @@ class PipelinedModel:
                 if j < c - 1:
                     stashes[j][mb] = x
                     return program("fwd", j, mb)(sparams[j], x, aux_of(mb))
-                loss_sum, w_sum, acc, gx = program("fwd_bwd", j, mb)(
+                loss_sum, acc, gx = program("fwd_bwd", j, mb)(
                     sparams[j], x, aux_of(mb), acc_of(j))
                 result["stage_grads"][j] = acc
                 stashes[j][mb] = gx
-                with spans.span(spans.LOSS_SYNC):
-                    result["loss_sum"] += float(loss_sum)
-                    result["weight_sum"] += float(w_sum)
-                return None
+                result["loss_sum"].parts.append(loss_sum)
+                return loss_sum
             return forward
 
         def make_backward(j):
@@ -393,9 +398,7 @@ class PipelinedModel:
                     sparams[j], stashes[j].pop(mb), g_out, aux_of(mb),
                     acc_of(j))
                 result["stage_grads"][j] = acc
-                if j == 0:
-                    return None
-                return gx
+                return gx if j > 0 else acc
             return backward
 
         def make_step(j):
@@ -530,6 +533,35 @@ class EncDecPipelinedModel(PipelinedModel):
     def _table(self, j: int) -> str:
         """The full-params key of stage ``j``'s T5 relative bias table."""
         return "enc_rel_bias" if j < self.n_enc_stages else "dec_rel_bias"
+
+
+class LossSum:
+    """A plan's summed loss, left on the device as one float32 scalar per
+    micro-batch, in the order the last stage produced them. ``float()``
+    reads them in one transfer and adds them on the host in that order:
+    the float a ``float()`` of each scalar as it came, added up, would
+    give."""
+
+    def __init__(self):
+        self.parts: list = []
+
+    def __len__(self) -> int:
+        return len(self.parts)
+
+    def __float__(self) -> float:
+        total = 0.0
+        for x in jax.device_get(self.parts):
+            total += float(x)
+        return total
+
+
+def weight_sum(batches: dict) -> float:
+    """The micro-batches' summed loss weights, from the host's arrays. It
+    equals the float32 sum ``_xent_sum`` computes on the device: the
+    weights are 0 or 1 and a micro-batch holds fewer than 2**24 tokens, so
+    both sums are exact."""
+    return sum(float(np.sum(b["loss_weights"], dtype=np.float32))
+               for b in batches.values())
 
 
 def _xent_sum(head_w, h, labels, weights, cfg: ArchConfig):

@@ -79,7 +79,8 @@ from repro.train.optimizer import AdamWConfig, init_opt_state
 # train/pipeline_adapter.py so dist/backend.py can import them without a
 # train.runner <-> dist.backend cycle. bench_e2e and older tests import
 # them from here.
-from repro.train.pipeline_adapter import (build_encdec_grad_step,  # noqa: F401
+from repro.train.pipeline_adapter import (LossSum,
+                                          build_encdec_grad_step,  # noqa: F401
                                           build_grad_step,
                                           model_cache_namespace)
 from repro.train.step_cache import CompiledStepCache
@@ -267,6 +268,7 @@ class PlanAheadRunner:
         self._calibrator = (OnlineCalibrator(cost)
                             if rcfg.calibrate else None)
         self._end = 0
+        self._pipeline_syncs = 0         # this iteration's waits for timing
 
     # ------------------------- planning side ---------------------------
     @staticmethod
@@ -379,7 +381,8 @@ class PlanAheadRunner:
 
     def _execute_replica(self, it: int, rep: int, plan: ExecutionPlan,
                          gb: GlobalBatch, params):
-        """One replica's plan -> (grads, loss_sum, weight_sum)."""
+        """One replica's plan -> (grads, loss_sum, weight_sum); the loss may
+        still be on the device (``LossSum``)."""
         if not plan.micro_batches:
             return None, 0.0, 0.0   # idle replica (fewer micro-batches than dp)
         with spans.span(spans.MATERIALIZE):
@@ -392,6 +395,7 @@ class PlanAheadRunner:
             plan, params=params, batches=batches, hook=hook,
             collect_timings=self._calibrator is not None,
             timeout=self.rcfg.exec_timeout)
+        self._pipeline_syncs += res.meta.get("pipeline_syncs", 0)
         if self._calibrator is not None and res.timings:
             by_id = {m.mb_id: m for m in plan.micro_batches}
             for kind, mb_id, secs in res.timings:
@@ -411,15 +415,17 @@ class PlanAheadRunner:
         """Every surviving replica's plan, executed here (one process stands
         in for the DP group), with their grads merged: the full-batch
         gradient, and so the loss trajectory, does not depend on how the
-        planner split work across replicas. Returns (grads, loss_sum,
-        weight_sum, per-replica seconds)."""
+        planner split work across replicas. Returns (grads, each replica's
+        loss sum, weight_sum, per-replica seconds). With a monitor, each
+        replica's seconds end with one wait for its work, counted in
+        ``_pipeline_syncs``."""
         if self._encdec and any(not isinstance(m.seq, (tuple, list))
                                 for m in plan.micro_batches):
             raise ValueError(
                 "enc-dec model got a decoder-only micro-batch: the stream "
                 "must carry (enc, dec) lengths with dec > 0 for every "
                 "sample (use encdec_fraction=1.0)")
-        grads, loss_sum, w_sum = None, 0.0, 0.0
+        grads, losses, w_sum = None, [], 0.0
         replica_s: dict[int, float] = {}
         for pos, rplan in enumerate(it_plan.replica_plans):
             rep = self._alive[pos] if pos < len(self._alive) else pos
@@ -430,15 +436,18 @@ class PlanAheadRunner:
                 ExecutionPlan.from_json(rplan.to_json())
             rt0 = time.perf_counter()
             g, ls, ws = self._execute_replica(it, rep, xplan, gb, params)
+            if self.monitor is not None and g is not None:
+                jax.block_until_ready(g)
+                self._pipeline_syncs += 1
             replica_s[rep] = time.perf_counter() - rt0
-            loss_sum += ls
+            losses.append(ls)
             w_sum += ws
             if g is not None and grads is not None:
                 with spans.span(spans.GRAD_MERGE):
                     grads = jax.tree.map(jnp.add, grads, g)
             elif g is not None:
                 grads = g
-        return grads, loss_sum, w_sum, replica_s
+        return grads, losses, w_sum, replica_s
 
     # ------------------------- recovery side ---------------------------
     def _drain(self) -> None:
@@ -600,9 +609,12 @@ class PlanAheadRunner:
                             predicted_compute_ms=1e3 * sum(
                                 m.t_fwd + m.t_bwd for m in micro),
                             **spans.encdec_tokens(gb.lengths, micro))
-                        grads, loss_sum, w_sum, replica_s = \
+                        self._pipeline_syncs = 0
+                        grads, losses, w_sum, replica_s = \
                             self._execute_replicas(it, plan, it_plan, gb,
                                                    params)
+                        it_span.set_metadata(
+                            pipeline_syncs=self._pipeline_syncs)
                     except (PipelineError, InjectedFault) as e:
                         stats.faults += 1
                         attempts += 1
@@ -621,10 +633,18 @@ class PlanAheadRunner:
                         params, opt, om = self.backend.optimizer_step(
                             params, grads, opt, self.opt_cfg,
                             grad_scale=1.0 / max(w_sum, 1.0))
-                    # the step's one sync: it waits for AdamW, so the
+                    # the step's sync: it waits for AdamW, so the
                     # iteration's time (and its span) include the update
                     with spans.span(spans.STEP_SYNC):
                         grad_norm = float(om["grad_norm"])
+                    # the step's losses, read once now that it is done, and
+                    # added in the order each replica produced them
+                    with spans.span(spans.LOSS_SYNC, n_reads=sum(
+                            len(ls) for ls in losses
+                            if isinstance(ls, LossSum))):
+                        loss_sum = 0.0
+                        for ls in losses:
+                            loss_sum += float(ls)
                     dt = time.perf_counter() - t0
                 if self.monitor is not None:
                     for rep in self._alive:

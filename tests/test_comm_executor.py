@@ -111,7 +111,7 @@ def test_pipeline_grads_match_sequential(n_stages):
     cbs, result = pm.make_callbacks(plan, batches)
     PipelineExecutor(plan, cbs, timeout=60).run()
     grads_pipe = pm.merge_stage_grads(result["stage_grads"])
-    loss_pipe = result["loss_sum"] / result["weight_sum"]
+    loss_pipe = float(result["loss_sum"]) / result["weight_sum"]
 
     def ref_loss(p, b):
         h, _, _ = MD.forward(p, b, cfg, mode="train")

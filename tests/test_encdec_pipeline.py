@@ -151,7 +151,7 @@ def test_pipelined_encdec_matches_sequential_oracle_bitwise():
     cbs, result = pm.make_callbacks(plan, batches)
     PipelineExecutor(plan, cbs, timeout=120).run()
     grads_pipe = pm.merge_stage_grads(result["stage_grads"])
-    loss_pipe = result["loss_sum"] / result["weight_sum"]
+    loss_pipe = float(result["loss_sum"]) / result["weight_sum"]
 
     fwd_loss = _oracle_fwd_loss()
     step = build_encdec_grad_step(CFG)

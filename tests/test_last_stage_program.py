@@ -125,7 +125,7 @@ def test_pipelined_matches_sequential_grad_step(pipelined):
         ls += float(loss_sum)
         ws += float(w_sum)
         gacc = g if gacc is None else jax.tree.map(jnp.add, gacc, g)
-    loss_pipe = result["loss_sum"] / result["weight_sum"]
+    loss_pipe = float(result["loss_sum"]) / result["weight_sum"]
     assert np.isfinite(loss_pipe)
     assert loss_pipe == ls / ws          # bit for bit
     grads = pm.merge_stage_grads(result["stage_grads"])

@@ -31,7 +31,7 @@ STREAM = StreamConfig(n_tasks=8, global_tokens=768, max_len=128,
 N_ITERS = 3
 MAIN = ["dynapipe.submit", "dynapipe.plan_wait", "dynapipe.materialize",
         "dynapipe.stage_setup", "dynapipe.pipeline", "dynapipe.grad_merge",
-        "dynapipe.optimizer", "dynapipe.step_sync"]
+        "dynapipe.optimizer", "dynapipe.step_sync", "dynapipe.loss_sync"]
 
 
 def _run(step_cache):
@@ -132,12 +132,22 @@ def test_stage_spans_on_stage_threads(traced):
         # one compute thread per stage, neither of them the runner's
         assert len(lines[0]) == len(lines[1]) == 1
         assert lines[0] != lines[1] and main not in lines[0] | lines[1]
-        # device_put in stage 0's forward, loss_sync in stage 1's
-        for name, j in ((spans.DEVICE_PUT, 0), (spans.LOSS_SYNC, 1)):
-            got = [s for s in inside if s[3] == name]
-            assert len(got) == h["n_micro"]
-            assert all(any(_inside(s, f) and f[0] == s[0] for f in inside
-                           if f[3] == spans.stage(j, "fwd")) for s in got)
+        # device_put in stage 0's forward
+        got = [s for s in inside if s[3] == spans.DEVICE_PUT]
+        assert len(got) == h["n_micro"]
+        assert all(any(_inside(s, f) and f[0] == s[0] for f in inside
+                       if f[3] == spans.stage(0, "fwd")) for s in got)
+        # loss_sync on no stage thread: the runner reads the step's losses
+        # once, after step_sync, one device scalar per micro-batch
+        assert not [s for s in sp if s[3] == spans.LOSS_SYNC
+                    and s[0] != main]
+    its = [s for s in sp if s[3] == spans.ITERATION]
+    for it, h in zip(its, history):
+        got = [s for s in sp if s[3] == spans.LOSS_SYNC and _inside(s, it)]
+        assert len(got) == 1 and got[0][4]["n_reads"] == h["n_micro"]
+        sync, = [s for s in sp if s[3] == spans.STEP_SYNC and _inside(s, it)]
+        assert sync[2] <= got[0][1]
+        assert it[4]["pipeline_syncs"] == 0
         waits = [s for s in inside if s[3] == spans.RECV_WAIT]
         assert waits and {s[0] for s in waits} <= lines[0] | lines[1]
 

@@ -197,7 +197,7 @@ def test_pipelined_t5_matches_sequential_oracle_bitwise():
         _, _, g = step(params, b)
         gacc = g if gacc is None else jax.tree.map(jnp.add, gacc, g)
 
-    assert result["loss_sum"] / result["weight_sum"] == ls / ws  # bitwise
+    assert float(result["loss_sum"]) / result["weight_sum"] == ls / ws  # bitwise
     assert float(jnp.abs(grads_pipe["enc_rel_bias"]).max()) > 0
     assert float(jnp.abs(grads_pipe["dec_rel_bias"]).max()) > 0
     for a, b in zip(jax.tree.leaves(grads_pipe), jax.tree.leaves(gacc)):
