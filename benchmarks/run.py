@@ -3,10 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows. The planning section runs the
 small-n fast-vs-reference dp_split comparison (full-size numbers take ~47
 minutes of reference DP — regenerate the tracked ``BENCH_planning.json``
-with a direct ``python -m benchmarks.bench_planning`` run). Roofline terms
-are derived from the compiled dry-run artifacts when experiments/dryrun is
-populated (run ``python -m repro.launch.dryrun --all`` first for that
-section).
+with a direct ``python -m benchmarks.bench_planning`` run).
 """
 from __future__ import annotations
 
@@ -32,13 +29,6 @@ def main() -> None:
         except Exception as e:
             failures.append((name, e))
             traceback.print_exc()
-
-    print("\n# Roofline (from dry-run artifacts, if present)", flush=True)
-    try:
-        from benchmarks import roofline
-        roofline.main()
-    except Exception as e:
-        print(f"roofline section skipped: {e}")
 
     if failures:
         raise SystemExit(f"{len(failures)} benchmark sections failed: "

@@ -9,7 +9,7 @@ Package layout (see docs/architecture.md for the data-flow walkthrough):
 - ``repro.models``  — pure-JAX model zoo (transformer / mamba / MoE stacks).
 - ``repro.train``   — optimizer, train state, checkpointing, pipeline
                       adapter, planner-driven training loop.
-- ``repro.launch``  — mesh factories and the multi-pod compile dry-run.
+- ``repro.launch``  — mesh factories, the compile cache, the CPU launcher.
 - ``repro.kernels`` — Pallas kernels + jnp reference implementations.
 
 The public surface re-exports lazily (PEP 562) so ``import repro`` stays

@@ -1,9 +1,8 @@
-"""Architecture & shape configuration system.
+"""Architecture configuration system.
 
-Every assigned architecture is a ``configs/<id>.py`` exporting ``CONFIG``
-(an :class:`ArchConfig` with the exact published dimensions) and registered
-in :data:`REGISTRY` here. Shapes (the assignment's 4 input-shape cells) are
-:class:`ShapeSpec` entries in :data:`SHAPES`.
+Every architecture is a ``configs/<id>.py`` exporting ``CONFIG`` (an
+:class:`ArchConfig` with the exact published dimensions) and listed in
+:data:`ARCH_IDS` here.
 
 Design notes
 ------------
@@ -31,26 +30,6 @@ class LayerSpec:
 
     mixer: str = "attn"      # "attn" | "attn_local" | "mamba"
     moe: bool = False        # MoE FFN instead of dense FFN
-
-
-@dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str                # "train" | "prefill" | "decode"
-    seq_len: int
-    global_batch: int
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-SHAPES: dict[str, ShapeSpec] = {
-    "train_4k": ShapeSpec("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524_288, 1),
-}
 
 
 @dataclass(frozen=True)
@@ -105,33 +84,10 @@ class ArchConfig:
     tie_embeddings: bool = False
     scale_embed: bool = False   # gemma: h *= sqrt(d_model) after lookup
     decode: bool = True         # encoder-only archs have no decode step
-    subquadratic: bool = False  # eligible for long_500k
     norm_eps: float = 1e-6
     mlp_gated: bool = True
     act: str = "silu"
     dtype: str = "bfloat16"
-    # ZeRO-3/FSDP: shard the bf16 compute params over the data axis too and
-    # gather per layer — required when params·2B/tp exceeds HBM (>= ~100B).
-    fsdp_params: bool = False
-    # Unroll the scan-over-periods (few-period archs, e.g. jamba's 9 x 8
-    # layers): lets GSPMD keep per-leaf grad shardings instead of a stacked
-    # while-carry accumulator that loses the tp/zero dims.
-    unroll_stack: bool = False
-    # --- perf-hillclimb knobs (EXPERIMENTS.md §Perf) ---
-    # Replicate attention projection weights over the model axis (kills the
-    # per-layer k/v gathers; sensible when attn params are small, e.g. <=2B
-    # models with fat vocabularies like gemma2).
-    attn_tp: bool = True
-    # Zero-pad the q-head count up to a multiple of the model axis INSIDE the
-    # forward (constant pads; outputs exactly unchanged) so attention runs
-    # head-parallel even for uneven head counts (40H/56H on a 16-way axis).
-    pad_heads: bool = False
-    # activation-checkpoint policy for the period scan:
-    # "nothing" (full remat) | "dots" (save matmul outputs) | "everything"
-    remat_policy: str = "nothing"
-    # Small-model mode: the model axis becomes extra DP (weights replicated,
-    # ZeRO over data x model) — see dist.sharding.pure_dp.
-    pure_dp: bool = False
 
     # ------------------------------------------------------------------
     @property
@@ -306,25 +262,3 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         n_patches=8 if cfg.n_patches else 0,
     )
 
-
-def cell_supported(cfg: ArchConfig, shape: ShapeSpec) -> tuple[bool, str]:
-    """Whether an (arch × shape) assignment cell is runnable (see DESIGN §6)."""
-    if shape.kind == "decode" and not cfg.decode:
-        return False, "encoder-only arch has no decode step"
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "pure full-attention arch; 500k decode needs sub-quadratic attention"
-    if shape.kind == "prefill" and not cfg.decode:
-        # encoder-only prefill == full encode forward; allowed.
-        return True, "encoder-only: prefill == full encode forward"
-    return True, ""
-
-
-def all_cells() -> list[tuple[str, str, bool, str]]:
-    """(arch, shape, runnable, reason) for the 10×4 assignment grid."""
-    out = []
-    for arch in ARCH_IDS[:10]:
-        cfg = get_arch(arch)
-        for shape in SHAPES.values():
-            ok, why = cell_supported(cfg, shape)
-            out.append((arch, shape.name, ok, why))
-    return out

@@ -26,5 +26,4 @@ CONFIG = ArchConfig(
     rope_theta=10_000.0,
     mlp_gated=True,
     act="gelu",
-    subquadratic=False,       # global layers are full attention -> long_500k skipped
 )

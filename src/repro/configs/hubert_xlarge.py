@@ -1,9 +1,9 @@
 """HuBERT X-Large: encoder-only audio transformer (wav2vec2-style backbone).
 
 [arXiv:2106.07447; unverified]
-Per assignment, the conv feature-extractor frontend is a STUB: input_specs()
-supplies precomputed frame embeddings (B, S, d_model). The head predicts the
-504 masked-unit targets. Encoder-only => no decode shapes (see DESIGN §6).
+The conv feature-extractor frontend is a stub: the batch carries
+precomputed frame embeddings (B, S, d_model). The head predicts the 504
+masked-unit targets. Encoder-only => no decode step.
 Positional information: the conv-positional frontend is part of the stub; the
 backbone here uses RoPE as the TPU-idiomatic stand-in (documented deviation).
 """

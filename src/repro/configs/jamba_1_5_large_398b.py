@@ -12,7 +12,6 @@ _PERIOD = tuple(
 )
 
 CONFIG = ArchConfig(
-    fsdp_params=True,
     name="jamba-1.5-large-398b",
     family="hybrid",
     source="[arXiv:2403.19887; hf]",
@@ -32,7 +31,6 @@ CONFIG = ArchConfig(
     ssm_expand=2,
     ssm_conv=4,
     use_rope=False,           # jamba uses no positional embedding (positions carried by SSM layers)
-    subquadratic=True,        # 1:7 mamba:attn => KV cache only on 1/8 layers; long_500k runnable
     mlp_gated=True,
     act="silu",
 )

@@ -7,7 +7,6 @@ multimodality is carried by the llava-next-34b [vlm] cell).
 from repro.configs.base import ArchConfig, LayerSpec
 
 CONFIG = ArchConfig(
-    fsdp_params=True,
     name="llama4-scout-17b-a16e",
     family="moe",
     source="[hf:meta-llama/Llama-4-Scout-17B-16E; unverified]",

@@ -1,10 +1,10 @@
 """LLaVA-NeXT-34B: VLM — Yi-34B language backbone + anyres vision tiling.
 
 [hf llava-hf/llava-v1.6-34b-hf; unverified]
-Per assignment, only the transformer BACKBONE is modeled; the vision tower is
-a stub: input_specs() supplies precomputed patch embeddings (anyres tiling
-of 4 tiles + base image at 576 patches each = 2880 patch positions) that the
-model prepends to the token embeddings.
+Only the transformer backbone is modeled; the vision tower is a stub: the
+batch carries precomputed patch embeddings (anyres tiling of 4 tiles + base
+image at 576 patches each = 2880 patch positions) that the model prepends to
+the token embeddings.
 """
 from repro.configs.base import ArchConfig, LayerSpec
 

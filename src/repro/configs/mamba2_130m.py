@@ -23,5 +23,4 @@ CONFIG = ArchConfig(
     ssm_conv=4,
     tie_embeddings=True,
     use_rope=False,
-    subquadratic=True,
 )

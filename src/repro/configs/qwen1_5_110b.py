@@ -5,7 +5,6 @@
 from repro.configs.base import ArchConfig, LayerSpec
 
 CONFIG = ArchConfig(
-    fsdp_params=True,
     name="qwen1.5-110b",
     family="dense",
     source="[hf:Qwen/Qwen1.5-0.5B; hf]",

@@ -4,8 +4,7 @@ Two implementations behind one interface:
 
 - :class:`AnalyticCostModel` — closed-form roofline model over TPU v5e
   constants (197 TFLOP/s bf16, 819 GB/s HBM). Used in this CPU-only container
-  wherever the paper would read a profiled table, and calibrated by the same
-  constants the dry-run roofline uses.
+  wherever the paper would read a profiled table.
 - :class:`ProfiledCostModel` — the paper's mechanism: measure fwd/bwd time
   and peak memory on a power-of-two (micro_batch, seq_len) grid and
   bilinearly interpolate in log2-space. ``profile_fn`` can wrap a real jitted

@@ -5,7 +5,7 @@ instruction streams, logical sharding dims); everything below it is JAX
 meshes and collectives. Three modules:
 
 - :mod:`repro.dist.sharding` — logical-axis sharding (``shard``,
-  ``spec_for``, ZeRO layouts, ``pure_dp``) over ``jax.sharding.Mesh``.
+  ``spec_for``, ZeRO layouts) over ``jax.sharding.Mesh``.
 - :mod:`repro.dist.pipeline` — pipeline execution: the compiled
   ``shard_map``+``ppermute`` device plane and the threaded host plane.
 - :mod:`repro.dist.backend` — the :class:`ExecutionBackend` protocol

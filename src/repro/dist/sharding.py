@@ -29,12 +29,7 @@ Resolution rules (all enforced by :func:`spec_for`):
    first dim that passes rule 2 takes it and the other is replicated, which
    is exactly the EP-or-expert-internal-TP fallback the models document.
 
-:func:`pure_dp` is a context manager that remaps every model-parallel
-logical name to nothing and ``dp`` to *all* mesh axes — the hillclimb's
-"use the model axis as extra data parallelism" mode. It only changes
-sharding, never math.
-
-ZeRO-1/3: :func:`zero1_logical` upgrades a parameter's logical tuple by
+ZeRO-1: :func:`zero1_logical` upgrades a parameter's logical tuple by
 assigning ``"zero"`` to the largest dim the DP axes divide (possibly
 combining with an existing ``tp`` dim); :func:`spec_for_zero` resolves the
 result. Gradients constrained to the ZeRO spec lower to reduce-scatters
@@ -52,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 LogicalDim = Union[str, None, tuple]
 
-# mesh-axis name classes; launch/mesh.py uses ("pod", "data", "model") and
+# mesh-axis name classes; tests use ("data", "model") meshes and
 # make_stage_mesh uses ("stage",) for the pipeline axis
 _BATCH_AXES = ("pod", "data", "dp", "batch", "replica")
 _MODEL_AXES = ("model", "tp", "mdl", "tensor")
@@ -62,7 +57,7 @@ _tls = threading.local()
 
 
 # ----------------------------------------------------------------------
-# ambient mesh + pure-DP mode
+# ambient mesh
 # ----------------------------------------------------------------------
 @contextlib.contextmanager
 def set_mesh(mesh: Mesh):
@@ -86,26 +81,6 @@ def ambient_mesh() -> Optional[Mesh]:
     return getattr(_tls, "mesh", None)
 
 
-def is_pure_dp() -> bool:
-    return bool(getattr(_tls, "pure_dp", False))
-
-
-@contextlib.contextmanager
-def pure_dp(enabled: bool = True):
-    """Treat every mesh axis as data parallelism while the context is open.
-
-    ``tp``/``sp``/``ep`` resolve to no axes (weights replicated) and ``dp``
-    resolves to the whole mesh. ``with pure_dp(False)`` is a no-op, so call
-    sites can pass a config flag straight through.
-    """
-    prev = getattr(_tls, "pure_dp", False)
-    _tls.pure_dp = bool(enabled)
-    try:
-        yield
-    finally:
-        _tls.pure_dp = prev
-
-
 # ----------------------------------------------------------------------
 # logical-name -> mesh-axes resolution
 # ----------------------------------------------------------------------
@@ -115,8 +90,6 @@ def axis_map(mesh: Optional[Mesh] = None) -> dict:
     if mesh is None:
         return {}
     names = tuple(mesh.axis_names)
-    if is_pure_dp():
-        return {"dp": names, "tp": (), "sp": (), "ep": (), "zero": names}
     batch = tuple(a for a in names if a in _BATCH_AXES)
     model = tuple(a for a in names if a in _MODEL_AXES)
     stage = tuple(a for a in names if a in _STAGE_AXES)
@@ -197,8 +170,8 @@ def shard(x: jax.Array, *logical: LogicalDim,
     ``shard(h, "dp", "sp", None)`` constrains batch over the data axes and
     sequence over the model axis. Dims that fail divisibility are silently
     replicated (rule 2), and without an ambient mesh this is the identity —
-    the property that lets one model source serve 1-device tests and the
-    512-device dry-run alike.
+    the property that lets one model source serve 1-device tests and
+    multi-device meshes alike.
     """
     mesh = mesh if mesh is not None else ambient_mesh()
     if mesh is None:

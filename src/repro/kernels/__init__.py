@@ -4,7 +4,7 @@
 default from :func:`default_impl`, overridable via the
 ``REPRO_KERNEL_IMPL`` environment variable). The attention kernels train
 through fused custom-VJP backward passes; ``ref`` stays the ground-truth
-oracle and the XLA-visible FLOP-counting path for the dry-run.
+oracle.
 """
 from repro.kernels.ops import (  # noqa: F401
     attention,

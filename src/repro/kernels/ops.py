@@ -3,9 +3,8 @@
 ``impl`` selects the compute path:
   - "pallas"     : pl.pallas_call targeting TPU (the production path)
   - "interpret"  : same kernel body, interpreted on CPU (used by tests)
-  - "ref"        : pure-jnp oracle — used (a) as ground truth, and (b) for
-                   the dry-run/roofline lowering, where XLA must see the
-                   FLOPs (custom calls are opaque to cost_analysis).
+  - "ref"        : pure-jnp oracle, the ground truth and the path where
+                   no Pallas kernel applies.
 
 The kernel paths carry ``jax.custom_vjp`` fused backward passes, so
 ``impl`` is *sticky under grad*: training steps differentiate straight

@@ -1,8 +1,7 @@
 """Pure-jnp reference oracles for every Pallas kernel.
 
 These are the ground truth the kernel tests ``assert_allclose`` against, and
-the fallback compute path used when Pallas is disabled (e.g. for XLA cost
-analysis in the dry-run, where custom-call FLOPs would be invisible).
+the compute path used when Pallas is disabled (``impl="ref"``).
 """
 from __future__ import annotations
 
@@ -132,9 +131,8 @@ def attention_ref_chunked(
     block_q: int = 512,
 ):
     """Same semantics as :func:`attention_ref`, but scanned over q blocks so
-    the (T, S) score matrix never materializes — this is the XLA-visible
-    compute path used for the dry-run/roofline lowering of long sequences
-    (the Pallas kernel is opaque to cost_analysis)."""
+    the (T, S) score matrix never materializes — the ``ref`` impl's path
+    for long sequences."""
     b, t, h, d = q.shape
     if t <= block_q or t % block_q:
         return attention_ref(

@@ -1,4 +1,4 @@
-"""Production mesh construction (assignment MULTI-POD DRY-RUN step 1).
+"""Mesh construction.
 
 Functions, not module-level constants — importing this module never touches
 JAX device state.
@@ -6,18 +6,6 @@ JAX device state.
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_stage_mesh(n_stages: int, *, axis: str = "stage"):
@@ -37,11 +25,3 @@ def make_stage_mesh(n_stages: int, *, axis: str = "stage"):
             f"have {len(devs)} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_stages} on CPU)")
     return Mesh(np.asarray(devs[:n_stages]), (axis,))
-
-
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host) devices exist — tests/examples."""
-    n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(1, n // data))
-    return make_mesh((data, model), ("data", "model"))

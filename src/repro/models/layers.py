@@ -1,13 +1,9 @@
 """Composable pure-JAX layers: norms, RoPE, GQA attention, MLP, MoE.
 
-Parameters are plain nested dicts; every ``init_*`` has a matching
-``*_logical`` returning the same-structured tree of *logical* sharding dim
-tuples — entries from {"dp", "tp", "sp", "ep", None} that
-``repro.dist.sharding.spec_for`` (and ``spec_for_zero`` for ZeRO layouts)
-resolves against the ambient mesh, dropping any dim the mesh axis does not
-divide. Activations are annotated in-line with
-``repro.dist.sharding.shard(x, *logical_dims)`` — a no-op without a mesh —
-so GSPMD propagates DP/TP/SP placements from those anchors.
+Parameters are plain nested dicts. Activations are annotated in-line with
+``repro.dist.sharding.shard(x, *logical_dims)`` — logical dims from {"dp",
+"tp", "sp", "ep", None}, resolved against the ambient mesh and a no-op
+without one — so GSPMD propagates DP/TP/SP placements from those anchors.
 
 dtype policy: params bf16 (cfg.dtype), math that needs it (softmax, norms,
 SSM recurrences, loss) in fp32.
@@ -16,7 +12,7 @@ Kernel contract: ``ops.attention`` consumes GQA k/v heads natively (no
 head repetition here or in the kernels) and is differentiable on every
 impl — the Pallas kernels carry fused custom-VJP backwards, so the
 ``impl`` a caller selects stays in force under ``jax.grad`` (``ref``
-remains the oracle and the dry-run/FLOP-counting path).
+remains the oracle).
 """
 from __future__ import annotations
 
@@ -75,15 +71,9 @@ def heads_even(cfg: ArchConfig) -> bool:
     attention (GQA kv heads smaller than the axis stay replicated — the
     ``shard`` helper drops uneven dims automatically). Uneven (gemma2 8H,
     starcoder2 36H, qwen2.5 40H, granite 24H, llama4 40H, llava 56H on a
-    16-way axis): weights stay sharded on the fused h·dh dim (always
-    divisible — FSDP-style gather at use) and the attention *compute* is
-    sequence-parallel instead (DESIGN §5/§6). ``cfg.pad_heads`` promotes
-    uneven archs to the even path via in-forward zero padding; ``attn_tp=
-    False`` demotes to the replicated-weight seq-parallel path."""
-    if not cfg.attn_tp:
-        return False
+    16-way axis): the attention *compute* is sequence-parallel instead."""
     tp = axis_size("tp")
-    return tp == 1 or cfg.n_heads % tp == 0 or cfg.pad_heads
+    return tp == 1 or cfg.n_heads % tp == 0
 
 
 def init_attention(key, cfg: ArchConfig):
@@ -110,49 +100,6 @@ def init_rel_bias(key, cfg: ArchConfig):
     """A stack's T5 relative bias table, (buckets, heads), N(0, d^-1/2)."""
     return _init(key, (cfg.rel_attn_buckets, cfg.n_heads), cfg.d_model ** -0.5,
                  _dtype(cfg))
-
-
-def attention_logical(cfg: ArchConfig):
-    if not cfg.attn_tp:  # hillclimb: replicate small attention weights
-        p = {"wq": (None, None), "wk": (None, None), "wv": (None, None),
-             "wo": (None, None)}
-        if cfg.qkv_bias:
-            p.update(bq=(None,), bk=(None,), bv=(None,))
-        return p
-    p = {
-        "wq": (None, "tp"),
-        "wk": (None, "tp"),
-        "wv": (None, "tp"),
-        "wo": ("tp", None),
-    }
-    if cfg.qkv_bias:
-        p["bq"] = ("tp",)
-        p["bk"] = ("tp",)
-        p["bv"] = ("tp",)
-    return p
-
-
-def _pad_heads(q, k, v, cfg: ArchConfig):
-    """Zero-pad q heads to a multiple of the model axis and expand kv heads
-    to per-q-head layout with the *real* GQA mapping (q_i -> kv_{i//group}),
-    so padded attention is head-parallel AND exactly equals the unpadded
-    model: padded q/k are constant zero => uniform softmax over zero v => 0,
-    and wo sees no padded rows (we slice back before the out-projection)."""
-    tp = axis_size("tp")
-    b, t, h, dh = q.shape
-    kv = k.shape[2]
-    hp = -(-h // tp) * tp
-    group = h // kv
-    qmap = jnp.asarray([min(i // group, kv - 1) for i in range(h)] +
-                       [0] * (hp - h), jnp.int32)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, hp - h), (0, 0)))
-    k = jnp.take(k, qmap, axis=2)
-    v = jnp.take(v, qmap, axis=2)
-    if hp > h:
-        mask = (jnp.arange(hp) < h).astype(k.dtype)[None, None, :, None]
-        k = k * mask
-        v = v * mask
-    return q, k, v, hp
 
 
 def attention_fwd(
@@ -186,10 +133,6 @@ def attention_fwd(
     if cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    h_used = h
-    if (cfg.pad_heads and mode == "train" and even
-            and h % max(axis_size("tp"), 1)):
-        q, k, v, h_used = _pad_heads(q, k, v, cfg)
     if even:
         # Megatron head-parallel attention
         q = shard(q, "dp", None, "tp", None)
@@ -237,8 +180,6 @@ def attention_fwd(
         out = shard(out, "dp", None, "tp", None)
     else:
         out = shard(out, "dp", "sp", None, None)   # sp auto-dropped when t==1
-    if h_used != h:
-        out = out[:, :, :h, :]                      # drop zero pad heads
     y = jnp.einsum("bthk,hkd->btd", out, p["wo"].reshape(h, dh, d))
     return shard(y, "dp", "sp", None), new_cache
 
@@ -256,13 +197,6 @@ def init_mlp(key, cfg: ArchConfig, d_ff: Optional[int] = None):
     }
     if cfg.mlp_gated:
         p["w_gate"] = _init(ks[2], (d, f), d ** -0.5, dt)
-    return p
-
-
-def mlp_logical(cfg: ArchConfig):
-    p = {"w_in": (None, "tp"), "w_out": ("tp", None)}
-    if cfg.mlp_gated:
-        p["w_gate"] = (None, "tp")
     return p
 
 
@@ -294,24 +228,6 @@ def init_moe(key, cfg: ArchConfig):
         p["w_gate"] = _init(ks[3], (e, d, f), d ** -0.5, dt)
     if cfg.n_shared_experts:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=cfg.n_shared_experts * cfg.d_ff_expert)
-    return p
-
-
-def moe_logical(cfg: ArchConfig):
-    # EP when E % tp == 0 (spec_for checks divisibility; a second logical dim
-    # mapping to the same mesh axis is ignored, so when the expert dim CAN be
-    # sharded these reduce to pure EP, and when it can't — granite's 40
-    # experts on a 16-way axis — the d_ff/"tp" dim takes over: expert-internal
-    # tensor parallelism, exactly the fallback documented in DESIGN §5).
-    p = {
-        "router": (None, None),
-        "w_in": ("ep", None, "tp"),
-        "w_out": ("ep", "tp", None),
-    }
-    if cfg.mlp_gated:
-        p["w_gate"] = ("ep", None, "tp")
-    if cfg.n_shared_experts:
-        p["shared"] = mlp_logical(cfg)
     return p
 
 

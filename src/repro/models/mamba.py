@@ -22,7 +22,7 @@ def _tp_ok(cfg: ArchConfig) -> bool:
     """Mamba internals are TP-sharded only when the SSD head count divides
     the model axis (e.g. jamba's 128 heads); otherwise the block runs in
     pure-DP mode to avoid GSPMD reshard storms at the head reshape
-    (mamba2-130m's 24 heads on a 16-way axis — see DESIGN §6)."""
+    (mamba2-130m's 24 heads on a 16-way axis)."""
     tp = axis_size("tp")
     return tp == 1 or cfg.ssm_heads % tp == 0
 
@@ -49,19 +49,6 @@ def init_mamba(key, cfg: ArchConfig):
         "D": jnp.ones((hh,), jnp.float32),
         "norm_w": jnp.zeros((di,), dt),
         "out_proj": _init(ks[2], (di, d), di ** -0.5, dt),
-    }
-
-
-def mamba_logical(cfg: ArchConfig):
-    return {
-        "in_proj": (None, "tp"),
-        "conv_w": (None, "tp"),
-        "conv_b": ("tp",),
-        "A_log": (None,),
-        "dt_bias": (None,),
-        "D": (None,),
-        "norm_w": ("tp",),
-        "out_proj": ("tp", None),
     }
 
 

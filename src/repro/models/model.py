@@ -1,12 +1,11 @@
-"""Model facade: init / logical specs / forward / loss / prefill / decode.
+"""Model facade: init / forward / loss / prefill / decode.
 
-One entry point per assignment shape kind:
-  train   -> ``loss_fn``            (lowered per micro-batch by the executor,
-                                     and as the dry-run ``train_step``)
+One entry point per mode:
+  train   -> ``loss_fn``            (lowered per micro-batch by the executor)
   prefill -> ``prefill``            (full-sequence forward, returns KV/SSM cache)
   decode  -> ``decode``             (one token against the cache)
 
-Batch schemas (all provided by the data pipeline / ``launch.dryrun.input_specs``):
+Batch schemas (all provided by the data pipeline):
   tokens : {tokens, labels, loss_weights, positions, segment_ids}
   mixed  : + patches (B, P, d_model) precomputed anyres embeddings (vlm stub)
   frames : {frames (B,S,d_model), mask, labels, loss_weights} (audio stub)
@@ -24,7 +23,7 @@ from repro.models import transformer as T
 LOSS_CHUNK = 512
 
 # ----------------------------------------------------------------------
-# init + logical specs
+# init
 # ----------------------------------------------------------------------
 def init_params(key, cfg: ArchConfig):
     ks = jax.random.split(key, 6)
@@ -44,24 +43,6 @@ def init_params(key, cfg: ArchConfig):
     if not cfg.tie_embeddings:
         p["head"] = L._init(ks[4], (cfg.vocab_padded, cfg.d_model),
                             cfg.d_model ** -0.5, dt)
-    return p
-
-
-def params_logical(cfg: ArchConfig):
-    # untied: embed D-sharded (cheap lookup), head vocab-sharded (cheap loss).
-    # tied: one table — vocab-sharded for the loss side, lookup pays a gather.
-    p = {
-        "embed": ("tp", None) if cfg.tie_embeddings else (None, "tp"),
-        "stack": T.stack_logical(cfg),
-        "final_norm": (None,),
-    }
-    if cfg.input_mode == "frames":
-        p["frame_adapter"] = (None, "tp")
-        p["mask_emb"] = (None,)
-    if cfg.input_mode == "mixed":
-        p["patch_adapter"] = (None, "tp")
-    if not cfg.tie_embeddings:
-        p["head"] = ("tp", None)
     return p
 
 
@@ -116,8 +97,7 @@ def _xent_chunk(head_w, h_c, labels_c, w_c, cfg: ArchConfig):
     lse = jax.nn.logsumexp(logits, axis=-1)
     # vocab-parallel label pick: a select-and-reduce over the (sharded) vocab
     # dim — GSPMD keeps it local + a scalar psum. (take_along_axis on a
-    # sharded dim all-gathers the whole logits chunk — measured at ~2e11
-    # link bytes/step for gemma2's 256k vocab; see EXPERIMENTS.md §Perf.)
+    # sharded dim all-gathers the whole logits chunk.)
     onehot = (jnp.arange(cfg.vocab_padded)[None, None, :]
               == labels_c[..., None])
     ll = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
@@ -165,8 +145,7 @@ def prefill(params, batch, cfg: ArchConfig, *, impl=None, cache_len=None):
     """Full-sequence forward. Returns (last_logits (B,Vp), cache).
 
     ``cache_len`` (>= seq len) sizes the KV cache so subsequent decode steps
-    have headroom; defaults to the prompt length (the dry-run's prefill_32k
-    measures exactly the 32k-token prefill)."""
+    have headroom; defaults to the prompt length."""
     b = batch["positions"].shape[0]
     s = cache_len or batch["positions"].shape[1]
     if cfg.decode:

@@ -37,18 +37,6 @@ def init_block(key, cfg: ArchConfig, spec: LayerSpec):
     return p
 
 
-def block_logical(cfg: ArchConfig, spec: LayerSpec):
-    p: dict = {"ln1": (None,)}
-    p["mixer"] = M.mamba_logical(cfg) if spec.mixer == "mamba" else L.attention_logical(cfg)
-    if spec.moe:
-        p["ln2"] = (None,)
-        p["ffn"] = L.moe_logical(cfg)
-    elif cfg.d_ff:
-        p["ln2"] = (None,)
-        p["ffn"] = L.mlp_logical(cfg)
-    return p
-
-
 def block_fwd(p, h, cfg: ArchConfig, spec: LayerSpec, *,
               positions, segment_ids, cache=None, cache_pos=None,
               mode="train", impl=None, rel_bias=None):
@@ -97,25 +85,6 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int, dtype=jnp.bfloat16):
     return tuple(caches)
 
 
-def cache_logical(cfg: ArchConfig):
-    out = []
-    for spec in cfg.layer_pattern:
-        if spec.mixer == "mamba":
-            out.append({
-                "conv": (None, "dp", None, "tp"),
-                "ssm": (None, "dp", "tp", None, None),
-            })
-        else:
-            # KV cache: batch over dp, seq over the model axis (flash-decode
-            # style sharding; kv heads are usually < 16 so seq is the only
-            # dimension that always divides).
-            out.append({
-                "k": (None, "dp", "sp", None, None),
-                "v": (None, "dp", "sp", None, None),
-            })
-    return tuple(out)
-
-
 # ----------------------------------------------------------------------
 # the stack
 # ----------------------------------------------------------------------
@@ -130,47 +99,6 @@ def init_stack(key, cfg: ArchConfig):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
 
 
-def stack_logical(cfg: ArchConfig):
-    one = {f"l{i}": block_logical(cfg, spec)
-           for i, spec in enumerate(cfg.layer_pattern)}
-    # prepend the periods axis (never sharded)
-    return jax.tree.map(lambda lg: (None,) + tuple(lg), one,
-                        is_leaf=lambda x: isinstance(x, tuple) and all(
-                            isinstance(e, (str, type(None))) for e in x))
-
-
-def _pin_fsdp(pparams, cfg: ArchConfig):
-    """Re-assert FSDP sharding on the per-period weight slice *inside* the
-    scan body, so GSPMD gathers one period at a time in-loop instead of
-    resharding the whole stacked tensor before the loop (which would
-    materialize the full model per device — defeating ZeRO-3)."""
-    from repro.dist.sharding import ambient_mesh, spec_for_zero, zero1_logical
-    mesh = ambient_mesh()
-    if mesh is None or not cfg.fsdp_params:
-        return pparams
-    logical = {f"l{i}": block_logical(cfg, spec)
-               for i, spec in enumerate(cfg.layer_pattern)}
-
-    def leafy(x):
-        return isinstance(x, tuple) and all(
-            isinstance(e, (str, type(None))) for e in x)
-
-    from repro.dist.sharding import spec_for
-
-    def pin(w, lg):
-        zlg = zero1_logical(tuple(lg), tuple(w.shape), mesh)
-        w = jax.lax.with_sharding_constraint(
-            w, spec_for_zero(tuple(w.shape), zlg, mesh))
-        # ...then explicitly gather back to the plain-TP layout, so the
-        # reshard is a (small) weight-side all-gather over data — and never
-        # an activation-side gather over model, which GSPMD's propagation
-        # otherwise sometimes picks (observed: full-d_ff hidden gathers).
-        return jax.lax.with_sharding_constraint(
-            w, spec_for(tuple(w.shape), tuple(lg), mesh))
-
-    return jax.tree.map(pin, pparams, logical, is_leaf=leafy)
-
-
 def stack_fwd(params, h, cfg: ArchConfig, *,
               positions, segment_ids, cache=None, cache_pos=None,
               mode="train", impl=None, remat=True, rel_bias=None):
@@ -179,7 +107,6 @@ def stack_fwd(params, h, cfg: ArchConfig, *,
 
     def period_fn(h, xs):
         pparams, pcache = xs
-        pparams = _pin_fsdp(pparams, cfg)
         new_caches = []
         aux_total = jnp.zeros((), jnp.float32)
         for i, spec in enumerate(cfg.layer_pattern):
@@ -195,28 +122,10 @@ def stack_fwd(params, h, cfg: ArchConfig, *,
         return h, (tuple(new_caches), aux_total)
 
     if remat:
-        policy = {
-            "nothing": jax.checkpoint_policies.nothing_saveable,
-            "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            "everything": jax.checkpoint_policies.everything_saveable,
-        }[cfg.remat_policy]
-        period_fn = jax.checkpoint(period_fn, policy=policy)
+        period_fn = jax.checkpoint(
+            period_fn, policy=jax.checkpoint_policies.nothing_saveable)
 
     cache_xs = cache if cache is not None else _none_like_periods(params, cfg)
-    if cfg.unroll_stack:
-        # python-unrolled periods: per-leaf grads keep their tp/zero specs
-        # (a scanned while-carry accumulator collapses them — DESIGN §5)
-        new_caches, auxs = [], []
-        for i in range(cfg.n_periods):
-            xs_i = (jax.tree.map(lambda x, i=i: x[i], params),
-                    jax.tree.map(lambda x, i=i: x[i], cache_xs))
-            h, (nc, aux) = period_fn(h, xs_i)
-            new_caches.append(nc)
-            auxs.append(aux)
-        new_cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
-                     if cache is not None else None)
-        return h, new_cache, jnp.sum(jnp.stack(auxs))
-
     xs = (params, cache_xs)
     h, (new_cache, aux) = jax.lax.scan(period_fn, h, xs)
     if cache is None:

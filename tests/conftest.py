@@ -1,7 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests see the real single CPU
-device (the 512-device override is exclusively the dry-run's, per the
-assignment). Multi-device sharding tests spawn subprocesses that set their
-own XLA_FLAGS before importing jax."""
+device. Multi-device sharding tests spawn subprocesses that set their own
+XLA_FLAGS before importing jax."""
 import os
 import sys
 import subprocess

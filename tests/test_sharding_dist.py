@@ -1,11 +1,17 @@
 """Distribution-layer tests. Multi-device cases run in subprocesses with
-their own XLA_FLAGS (tests themselves stay single-device, per assignment)."""
+their own XLA_FLAGS (the test process itself stays single-device)."""
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import spec_for, spec_for_zero, zero1_logical
+from repro.configs.base import get_arch, reduced
+from repro.dist.sharding import (ambient_mesh, axis_size, set_mesh, shard,
+                                 spec_for, spec_for_zero, zero1_logical)
+from repro.models import layers as L
 from tests.conftest import run_subprocess_devices
 
 
@@ -17,22 +23,17 @@ def test_zero1_logical_no_mesh():
     assert zero1_logical((None, "tp"), (64, 64)) == (None, "tp")
 
 
-def test_pure_dp_spec_roundtrip_one_device_mesh():
-    """The fallback path launch/dryrun.py uses for pure-DP cells: on a
-    1-device mesh every spec must collapse to fully-replicated, constraints
-    must be identity, and values must round-trip through them unchanged."""
-    import jax
-    import jax.numpy as jnp
-    from repro.dist.sharding import (ambient_mesh, axis_size, pure_dp,
-                                     set_mesh, shard)
-
+def test_specs_collapse_on_one_device_mesh():
+    """On a 1-device mesh every spec must collapse to fully-replicated,
+    constraints must be identity, and values must round-trip through them
+    unchanged."""
     mesh = jax.make_mesh((1,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     x = np.arange(32, dtype=np.float32).reshape(4, 8)
-    with set_mesh(mesh), pure_dp(True):
+    with set_mesh(mesh):
         assert ambient_mesh() is mesh
         assert axis_size("dp") == 1 and axis_size("tp") == 1
-        # dp resolves to the whole (trivial) mesh; tp resolves to nothing
+        # a size-1 axis shards nothing; the mesh has no model axis at all
         assert spec_for((4, 8), ("dp", "tp")) == P()
         zlg = zero1_logical((None, "tp"), (64, 64), mesh)
         assert spec_for_zero((64, 64), zlg, mesh) == P()
@@ -41,14 +42,34 @@ def test_pure_dp_spec_roundtrip_one_device_mesh():
     assert ambient_mesh() is None
 
 
+@pytest.mark.parametrize("n_heads,tp,even", [
+    (6, 1, True),       # no model axis: every head count is even
+    (8, 4, True),       # heads divide the axis: head-parallel attention
+    (6, 4, False),      # they do not: sequence-parallel attention
+], ids=["axis-1", "divides", "uneven"])
+def test_heads_even_rule(monkeypatch, n_heads, tp, even):
+    cfg = dataclasses.replace(reduced(get_arch("qwen2.5-32b")),
+                              n_heads=n_heads, n_kv_heads=2)
+    orig = L.axis_size
+    monkeypatch.setattr(
+        L, "axis_size", lambda d, mesh=None: tp if d == "tp" else orig(d, mesh))
+    assert L.heads_even(cfg) is even
+
+
 @pytest.mark.slow
-def test_sharded_loss_equals_unsharded():
+@pytest.mark.parametrize("arch,overrides", [
+    ("qwen2.5-32b", {}),
+    ("gemma2-2b", {}),
+    # 6 heads on a 4-way model axis: the sequence-parallel attention path
+    ("qwen2.5-32b", {"n_heads": 6, "n_kv_heads": 2}),
+], ids=["qwen2.5-32b", "gemma2-2b", "uneven-heads"])
+def test_sharded_loss_equals_unsharded(arch, overrides):
     """jit'd loss under a (2,4) mesh == single-device loss (GSPMD math)."""
-    out = run_subprocess_devices("""
-import jax, jax.numpy as jnp, numpy as np
+    out = run_subprocess_devices(f"ARCH, OVERRIDES = {arch!r}, {overrides!r}" + """
+import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_arch, reduced
 from repro.models import model as MD
-cfg = reduced(get_arch("qwen2.5-32b"))
+cfg = dataclasses.replace(reduced(get_arch(ARCH)), **OVERRIDES)
 key = jax.random.PRNGKey(0)
 params = MD.init_params(key, cfg)
 B, S = 4, 32
@@ -117,18 +138,3 @@ print("PIPE_OK")
 """, n_devices=2)
     assert "PIPE_OK" in out
 
-
-@pytest.mark.slow
-def test_dryrun_single_cell_small():
-    """The dry-run machinery end-to-end on the 512-device mesh for the
-    smallest cell (mamba2-130m long_500k decode) — fast compile."""
-    out = run_subprocess_devices("""
-from repro.launch.dryrun import run_cell
-rec = run_cell("mamba2-130m", "long_500k", multi_pod=False, save=False,
-               verbose=False)
-assert rec["runnable"]
-assert rec["memory"]["device_bytes_est"] < 16e9
-assert rec["cost"]["hlo_flops_per_device"] > 0
-print("DRYRUN_OK", rec["memory"]["device_bytes_est"])
-""", n_devices=512, timeout=900)
-    assert "DRYRUN_OK" in out
